@@ -2,9 +2,10 @@
 
 Every benchmark prints CSV rows ``name,value,derived`` (value in ms unless
 stated) and returns them for run.py to aggregate into bench_output.txt.
-All latencies are derived from the calibrated analytical cost model (this
-container has no accelerator — see DESIGN.md §7); live CPU measurements on
-smollm-135m validate mechanisms in tests/ and examples/.
+The latencies of the paper figures come from the calibrated analytical
+cost model, not from a device.  The ``--measured`` modes run the reduced
+configs live; run on the CPU (``JAX_PLATFORMS=cpu``) they check mechanisms,
+not speed.  The serving path on a TPU is checked by ``chip_smoke.py``.
 """
 
 from __future__ import annotations

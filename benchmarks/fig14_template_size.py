@@ -214,4 +214,6 @@ def _cli_kv_int8(argv) -> bool:
 
 
 if __name__ == "__main__":
+    from repro.utils import enable_compile_cache
+    enable_compile_cache()
     main(paged="--paged" in sys.argv, kv_int8=_cli_kv_int8(sys.argv))

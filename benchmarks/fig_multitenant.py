@@ -268,4 +268,6 @@ def main(measured: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.utils import enable_compile_cache
+    enable_compile_cache()
     main(measured="--measured" in sys.argv)

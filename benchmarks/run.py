@@ -45,4 +45,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.utils import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -145,6 +145,12 @@ class WeightStreamer:
         if self._error is not None:
             raise self._error
 
+    def release(self, keys) -> None:
+        """Drop landed slices that a consumer now holds elsewhere (stacked
+        into a full leaf); their device buffers free with the last use."""
+        for key in keys:
+            self._arrays.pop(key, None)
+
 
 class ForkSession:
     """The materialized state of one forked invocation."""
@@ -156,8 +162,11 @@ class ForkSession:
         # path -> either ("whole",) or ("sliced", n_layers)
         self.leaf_index = leaf_index
         self._params = None
+        self._leaves: dict = {}          # path -> leaf of ``_params``
 
     def leaf(self, path: str):
+        if path in self._leaves:
+            return self._leaves[path]
         if path in self.streamer.resident:
             return self.streamer.resident[path]
         if path in self.streamer.dynamic:
@@ -170,18 +179,32 @@ class ForkSession:
         return jnp.stack(slices)
 
     def block_slice(self, path: str, layer: int):
+        if path in self._leaves:
+            return self._leaves[path][layer]
         kind = self.leaf_index[path]
         if kind[0] == "whole":
             return self.streamer.get((path, ()))[layer]
         return self.streamer.get((path, (layer,)))
 
     def params(self):
-        """Full params pytree (waits for every outstanding transfer)."""
+        """Full params pytree (waits for every outstanding transfer).
+
+        Stacking copies a leaf's streamed layer slices into it, so they are
+        released leaf by leaf: a forked model then occupies its size once
+        on the device, not twice."""
         if self._params is None:
             specs = self.model.init_params(abstract=True)
             flat, treedef = jax.tree_util.tree_flatten_with_path(specs)
-            leaves = [self.leaf(path_str(p)) for p, _ in flat]
+            paths = [path_str(p) for p, _ in flat]
+            leaves = []
+            for path in paths:
+                leaves.append(self.leaf(path))
+                kind = self.leaf_index.get(path, ("whole",))
+                layers = [()] if kind[0] == "whole" else [
+                    (l,) for l in range(kind[1])]
+                self.streamer.release([(path, idx) for idx in layers])
             self._params = jax.tree_util.tree_unflatten(treedef, leaves)
+            self._leaves = dict(zip(paths, leaves))
         return self._params
 
 

@@ -38,10 +38,7 @@ from typing import Callable, Sequence
 import jax
 import numpy as np
 
-try:
-    from jax.extend.core import Literal as _Literal
-except Exception:  # pragma: no cover - jax version fallback
-    from jax.core import Literal as _Literal
+from jax.extend.core import Literal as _Literal
 
 from repro.utils import path_str
 

@@ -117,6 +117,12 @@ class ShardingPlan:
         del model
         return to_named(paged_cache_specs(cache_tree, self.mesh), self.mesh)
 
+    def init_params(self, model, rng):
+        """Seeded params created directly in their tensor-parallel layout:
+        a model that needs several chips is never built whole on one."""
+        return jax.jit(model.init_params,
+                       out_shardings=self.param_shardings(model))(rng)
+
 
 def serving_plan(mesh: Mesh) -> ShardingPlan:
     """Tensor-parallel serving plan: TP over 'model', no FSDP (serving

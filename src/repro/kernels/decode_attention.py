@@ -8,9 +8,9 @@ TPU adaptation notes:
     all G=H/KV query heads of one KV head form the [G, d] tile processed
     together, so GQA costs one KV pass regardless of G (the MXU contraction
     is [G x d] @ [d x bk]);
-  * variable cache occupancy is handled with a per-batch ``length`` scalar
-    (SMEM) masking the tail block — the serve path grows the cache position
-    per step without re-tiling.
+  * variable cache occupancy is handled with per-batch lengths that ride
+    in as a SCALAR-PREFETCH argument (SMEM) masking the tail block — the
+    serve path grows the cache position per step without re-tiling.
 """
 
 from __future__ import annotations
@@ -30,11 +30,12 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
             *, scale: float, bk: int, nk: int):
     """Grid point (b, h, t): one [bk, d] KV block of batch b, KV head h.
 
-    ``len_ref`` is the [1] per-batch length in SMEM.  Scratch: ``acc_ref``
+    ``len_ref`` is the [B] lengths vector in SMEM.  Scratch: ``acc_ref``
     [G, d] fp32 accumulator, ``m_ref``/``l_ref`` [G, 1] running max /
     normalizer — persistent across the innermost (sequential) KV-block
     axis, initialized at t == 0, emitted at t == nk-1.
     """
+    b = pl.program_id(0)
     t = pl.program_id(2)
 
     @pl.when(t == 0)
@@ -43,7 +44,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    length = len_ref[0]
+    length = len_ref[b]
     # skip blocks entirely past the valid cache region
     @pl.when(t * bk < length)
     def _compute():
@@ -102,23 +103,26 @@ def decode_attention(q, k, v, length, *, block_k: int = 256,
     qg = q.reshape(B, KV, G, d)
 
     kernel = functools.partial(_kernel, scale=scale, bk=bk, nk=nk)
-    out = pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,                  # per-batch lengths
         grid=(B, KV, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, t: (b,),
-                         memory_space=pltpu.SMEM),       # per-batch length
-            pl.BlockSpec((1, 1, G, d), lambda b, h, t: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b, h, t: (b, h, t, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b, h, t: (b, h, t, 0)),
+            pl.BlockSpec((1, 1, G, d), lambda b, h, t, ln: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, bk, d), lambda b, h, t, ln: (b, h, t, 0)),
+            pl.BlockSpec((1, 1, bk, d), lambda b, h, t, ln: (b, h, t, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, d), lambda b, h, t: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, d), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, G, d),
+                               lambda b, h, t, ln: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, d), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, d), q.dtype),
         interpret=interpret,
     )(lengths, qg, k, v)
     return out.reshape(B, H, d)

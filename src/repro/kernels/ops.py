@@ -1,8 +1,9 @@
 """jit'd wrappers around the Pallas kernels + XLA fallbacks.
 
-The model layer calls these through ``cfg.attn_impl``:
-  * 'xla'    — pure-jnp reference path (runs everywhere, default on CPU);
-  * 'pallas' — TPU kernels (validated in interpret mode on CPU).
+The model layer calls these when ``cfg.attn_impl`` resolves to Pallas
+(``models.config.use_pallas``: on a TPU by default, or when a test pins
+``attn_impl='pallas'`` to run the kernels in interpret mode on the CPU);
+``impl='xla'`` selects the pure-jnp reference instead.
 
 Wrappers own the layout glue (head-major transposes, block-size selection,
 shape-divisibility fallbacks) so kernels stay minimal.
@@ -20,9 +21,8 @@ other jnp code.
 
 from __future__ import annotations
 
-
+import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
@@ -45,10 +45,12 @@ from repro.kernels.ssd_scan import ssd_scan as _ssd_pallas
 
 
 def _pick_block(S: int, want: int = 128) -> int:
-    b = min(want, S)
-    while S % b:
-        b //= 2
-    return max(b, 1)
+    """The largest tile of at most ``want`` rows that divides ``S`` and that
+    the TPU tiling accepts: a multiple of 8, or else the whole axis."""
+    for b in range(min(want, S) // 8 * 8, 0, -8):
+        if S % b == 0:
+            return b
+    return S
 
 
 def flash_attention(q, k, v, causal: bool = True, softcap: float = 0.0,
@@ -58,11 +60,11 @@ def flash_attention(q, k, v, causal: bool = True, softcap: float = 0.0,
         return ref.flash_attention_ref(q, k, v, causal=causal, softcap=softcap)
     if _model_shards(mesh, q.shape[1], k.shape[1]) > 1:
         hs = P(None, "model", None, None)        # split the head axis
-        return shard_map(
+        return jax.shard_map(
             lambda qs, ks, vs: flash_attention(qs, ks, vs, causal=causal,
                                                softcap=softcap, impl=impl),
             mesh=mesh, in_specs=(hs, hs, hs), out_specs=hs,
-            check_rep=False)(q, k, v)
+            check_vma=False)(q, k, v)
     bq = _pick_block(q.shape[2])
     bk = _pick_block(k.shape[2])
     return _flash_pallas(q, k, v, causal=causal, block_q=bq, block_k=bk)
@@ -79,13 +81,13 @@ def decode_attention(q, k, v, length, impl: str = "pallas", mesh=None):
         length = jnp.broadcast_to(jnp.asarray(length, jnp.int32).reshape(-1),
                                   (q.shape[0],))
         hs = P(None, "model", None, None)
-        return shard_map(
+        return jax.shard_map(
             lambda qs, ks, vs, ln: decode_attention(qs, ks, vs, ln,
                                                     impl=impl),
             mesh=mesh,
             in_specs=(P(None, "model", None), hs, hs, P(None)),
             out_specs=P(None, "model", None),
-            check_rep=False)(q, k, v, length)
+            check_vma=False)(q, k, v, length)
     bk = _pick_block(k.shape[2], want=256)
     return _decode_pallas(q, k, v, length, block_k=bk)
 
@@ -110,7 +112,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
         # against locally-resident pages; the page table and lengths are
         # replicated host-driven control state
         if k_scales is None:
-            return shard_map(
+            return jax.shard_map(
                 lambda qs, ks, vs, pt, ln: paged_decode_attention(
                     qs, ks, vs, pt, ln, impl=impl),
                 mesh=mesh,
@@ -118,11 +120,11 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
                           P(None, None, "model", None),
                           P(None, None, "model", None), P(), P()),
                 out_specs=P(None, "model", None),
-                check_rep=False)(q, k_pages, v_pages, page_table, lengths)
+                check_vma=False)(q, k_pages, v_pages, page_table, lengths)
         # scale arenas shard with their value leaves' KV-head axis (or sit
         # replicated if paged_cache_specs couldn't split it — but head
         # divisibility was just checked, so 'model' applies here)
-        return shard_map(
+        return jax.shard_map(
             lambda qs, ks, vs, ksc, vsc, pt, ln: paged_decode_attention(
                 qs, ks, vs, pt, ln, k_scales=ksc, v_scales=vsc, impl=impl),
             mesh=mesh,
@@ -130,7 +132,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
                       P(None, None, "model", None), P(None, None, "model"),
                       P(None, None, "model"), P(), P()),
             out_specs=P(None, "model", None),
-            check_rep=False)(q, k_pages, v_pages, k_scales, v_scales,
+            check_vma=False)(q, k_pages, v_pages, k_scales, v_scales,
                              page_table, lengths)
     # kernel wants the head-major arena [P, KV, ps, d] — same per-step
     # transpose the dense decode path pays for its [B, T, KV, hd] cache

@@ -40,11 +40,21 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = float(np.finfo(np.float32).min)
 
 
-def _accumulate(q, k, v, t, length, scale, ps, acc_ref, m_ref, l_ref):
-    """One online-softmax step over a [ps, d] K/V block (fp32 in VMEM)."""
+def _accumulate(q, k, v, t, length, scale, ps, acc_ref, m_ref, l_ref,
+                k_row_scale=None, v_row_scale=None):
+    """One online-softmax step over a [ps, d] K/V block (fp32 in VMEM).
+
+    ``k_row_scale``/``v_row_scale`` ([1, ps], int8 arenas) scale each K/V
+    row.  A row scale of K is a column scale of the [G, ps] scores, and a
+    row scale of V is a column scale of the probabilities it multiplies,
+    so both apply lane-wise to [G, ps] tiles and the int8 block is never
+    rescaled row by row.
+    """
     scores = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale      # [G, ps]
+    if k_row_scale is not None:
+        scores = scores * k_row_scale
     cols = t * ps + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
     scores = jnp.where(cols < length, scores, NEG_INF)
 
@@ -53,8 +63,9 @@ def _accumulate(q, k, v, t, length, scale, ps, acc_ref, m_ref, l_ref):
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(scores - m_new)
     l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    pv = p if v_row_scale is None else p * v_row_scale
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
+        pv, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
@@ -97,10 +108,10 @@ def _dequant_kernel(pt_ref, len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
                     nb: int):
     """Like ``_kernel`` but K/V blocks arrive int8 with per-row scales.
 
-    ``ks_ref``/``vs_ref`` are [1, 1, ps] float32 scale blocks steered by
-    the same ``page_table[b, t]`` index map as their value blocks; each
-    block dequantizes in VMEM (``int8 row * scale``) before accumulation,
-    so fp K/V exists only block-at-a-time on-core.
+    ``ks_ref``/``vs_ref`` are [1, 1, 1, ps] float32 scale blocks steered
+    by the same ``page_table[b, t]`` index map as their value blocks; the
+    scales fold into the score and probability tiles (see
+    ``_accumulate``), so fp K/V is never materialized.
     """
     b = pl.program_id(0)
     t = pl.program_id(2)
@@ -116,9 +127,10 @@ def _dequant_kernel(pt_ref, len_ref, q_ref, k_ref, ks_ref, v_ref, vs_ref,
     @pl.when(t * ps < length)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)                      # [G, d]
-        k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0][:, None]
-        v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0][:, None]
-        _accumulate(q, k, v, t, length, scale, ps, acc_ref, m_ref, l_ref)
+        k = k_ref[0, 0].astype(jnp.float32)                      # [ps, d]
+        v = v_ref[0, 0].astype(jnp.float32)
+        _accumulate(q, k, v, t, length, scale, ps, acc_ref, m_ref, l_ref,
+                    k_row_scale=ks_ref[0, 0], v_row_scale=vs_ref[0, 0])
 
     @pl.when(t == nb - 1)
     def _finalize():
@@ -168,14 +180,16 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
         in_specs = [q_spec, kv_spec, kv_spec]
         operands = (page_table, lengths, qg, k_pages, v_pages)
     else:
-        scale_spec = pl.BlockSpec((1, 1, ps),
-                                  lambda b, h, t, pt, ln: (pt[b, t], h, 0))
+        # a unit axis before the in-page axis makes the scale block's last
+        # two dims (1, ps) equal the array's, as the TPU tiling requires
+        scale_spec = pl.BlockSpec((1, 1, 1, ps),
+                                  lambda b, h, t, pt, ln: (pt[b, t], h, 0, 0))
         kernel = functools.partial(_dequant_kernel, scale=scale, ps=ps,
                                    nb=NB)
         in_specs = [q_spec, kv_spec, scale_spec, kv_spec, scale_spec]
         operands = (page_table, lengths, qg,
-                    k_pages, k_scales.astype(jnp.float32),
-                    v_pages, v_scales.astype(jnp.float32))
+                    k_pages, k_scales.astype(jnp.float32)[:, :, None],
+                    v_pages, v_scales.astype(jnp.float32)[:, :, None])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                  # page table + lengths
         grid=(B, KV, NB),

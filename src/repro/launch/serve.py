@@ -1,10 +1,14 @@
 """Serving driver: deploy LLM functions on the full TIDAL stack and serve
-a request stream end-to-end through the FaaS runtime (live on CPU with
-reduced configs; the same code path serves full configs on a real TPU
-slice).
+a request stream end-to-end through the FaaS runtime.
 
     PYTHONPATH=src python -m repro.launch.serve \
         --arch smollm-135m --functions 3 --requests 12 --lora
+
+serves the architecture's published config (on a TPU the attention runs
+the Pallas kernels).  ``--smoke`` swaps in the reduced config of the same
+family for CPU rehearsals (``JAX_PLATFORMS=cpu``; kernels, where a test
+pins them, run in interpret mode); ``--layers N`` cuts depth only.  The
+first line names the platform and device the run is on.
 
 Per request the runtime picks the service class itself: ``cold`` (first
 invocation), ``fork`` (adaptive state forking from the template, prefill
@@ -13,8 +17,9 @@ batching engine — no forking at all).  Every TTFT feeds back into the
 template's Eq. 1 residency budget.
 
 ``--tp N`` serves tensor-parallel over N devices; ``--instances K`` runs
-K serving instances (one per mesh data-slice) with locality routing.  On
-a CPU host the needed devices are forced via XLA_FLAGS automatically.
+K serving instances (one per mesh data-slice) with locality routing.
+Params are created directly in their tensor-parallel layout.  Under
+``--smoke`` the needed host devices are forced via XLA_FLAGS.
 
 ``--open-loop --qps Q [--deadline D]`` switches from the closed loop
 (submit, wait, repeat) to an OPEN-loop Poisson driver over the async
@@ -48,8 +53,10 @@ def _flag_value(argv: list, flag: str, default: int) -> int:
 
 
 def _force_host_devices_from_argv() -> None:
-    """Set XLA_FLAGS before jax initializes a backend (import-time, like
-    the dry-run): --tp/--instances need tp*instances host devices."""
+    """Set XLA_FLAGS before jax initializes a backend: a --smoke rehearsal
+    of --tp/--instances needs tp*instances host devices."""
+    if "--smoke" not in sys.argv:
+        return
     n = (_flag_value(sys.argv, "--tp", 1)
          * _flag_value(sys.argv, "--instances", 1))
     flags = os.environ.get("XLA_FLAGS", "")
@@ -66,10 +73,10 @@ import numpy as np
 
 from repro.core import api as tidal
 from repro.data.pipeline import make_prompts
-from repro.models.registry import get_smoke_model
+from repro.models.registry import get_config, get_model, get_smoke_model
 from repro.runtime.faas import FaaSRuntime
 from repro.runtime.gateway import DeadlineExceeded, InvocationRequest
-from repro.utils import fmt_bytes
+from repro.utils import enable_compile_cache, fmt_bytes
 
 
 def _serve_open_loop(rt: FaaSRuntime, model, args, rng) -> None:
@@ -124,8 +131,11 @@ def main():
     ap.add_argument("--keep-alive", type=float, default=60.0)
     ap.add_argument("--lora", action="store_true",
                     help="deploy dynamic (LoRA) function variants")
-    ap.add_argument("--layers", type=int, default=6,
-                    help="reduced depth for live CPU execution")
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the reduced config of the family (CPU "
+                         "rehearsals) instead of the published one")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the depth to this many layers (widths stay)")
     ap.add_argument("--tp", type=int, default=1,
                     help="tensor-parallel degree per serving instance")
     ap.add_argument("--instances", type=int, default=1,
@@ -157,18 +167,27 @@ def main():
                          "prefix KV")
     args = ap.parse_args()
 
+    enable_compile_cache()
+    dev = jax.devices()[0]
+    print(f"platform {dev.platform} ({dev.device_kind}) x "
+          f"{jax.device_count()}, jax {jax.__version__}")
     mesh = None
     if args.tp > 1 or args.instances > 1:
         if jax.device_count() < args.tp * args.instances:
             raise SystemExit(
                 f"need {args.tp * args.instances} devices, have "
-                f"{jax.device_count()} (run as a script so XLA_FLAGS is "
-                "forced before jax initializes)")
+                f"{jax.device_count()} (a CPU rehearsal takes --smoke, run "
+                "as a script so XLA_FLAGS is forced before jax initializes)")
         mesh = jax.make_mesh((args.instances, args.tp), ("data", "model"))
         print(f"serving mesh: {args.instances} instance(s) x "
               f"{args.tp}-way tensor parallel")
 
-    model = get_smoke_model(args.arch, n_layers=args.layers)
+    depth = {} if args.layers is None else {"n_layers": args.layers}
+    model = (get_smoke_model(args.arch, **depth) if args.smoke
+             else get_model(get_config(args.arch).replace(**depth)))
+    cfg = model.cfg
+    print(f"model {cfg.name}: {cfg.n_layers} layers, d_model {cfg.d_model}, "
+          f"vocab {cfg.vocab_size}, {cfg.dtype}")
     rt = FaaSRuntime(n_slots=args.slots,
                      max_len=args.prompt_len + args.max_new,
                      keep_alive_s=args.keep_alive,
@@ -186,8 +205,11 @@ def main():
               f"{fmt_bytes(args.prefix_budget)}")
 
     rng = np.random.default_rng(0)
+    plan = rt.instances[0].plan
     for i in range(args.functions):
-        params = model.init_params(jax.random.PRNGKey(i))
+        key = jax.random.PRNGKey(i)
+        params = (model.init_params(key) if plan is None
+                  else plan.init_params(model, key))
         name = f"fn-{i}"
         if args.lora:
             fn = tidal.lora_function(name, model, params,

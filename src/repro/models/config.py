@@ -67,7 +67,9 @@ class ModelConfig:
     norm_eps: float = 1e-6
     dtype: str = "float32"               # compute/param dtype for live runs
     remat: bool = True                   # checkpoint the scanned block in training
-    attn_impl: str = "xla"               # 'xla' | 'pallas' (TPU kernels)
+    # 'auto' (Pallas kernels on a TPU, the XLA reference elsewhere) |
+    # 'xla' | 'pallas' (tests pin one path; see ``use_pallas``)
+    attn_impl: str = "auto"
     # decode-time sharding constraint: keep attention scores partitioned
     # over ('data', ..., 'model'-on-seq) — flash-decoding SPMD layout
     # (hillclimb #1, see EXPERIMENTS.md §Perf)
@@ -101,6 +103,16 @@ class ModelConfig:
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
+
+
+def use_pallas(cfg: ModelConfig) -> bool:
+    """True when attention runs the Pallas kernels: on a TPU under
+    ``attn_impl='auto'``, or wherever a caller pins ``'pallas'`` (on the
+    CPU the kernels then run in interpret mode)."""
+    if cfg.attn_impl == "auto":
+        import jax
+        return jax.default_backend() == "tpu"
+    return cfg.attn_impl == "pallas"
 
 
 def reduced(cfg: ModelConfig, **extra) -> ModelConfig:

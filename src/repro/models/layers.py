@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.config import ModelConfig
+from repro.models.config import ModelConfig, use_pallas
 
 
 class ParamFactory:
@@ -236,7 +236,7 @@ def attention_block(
             ck = ck.at[pages, off].set(k[:, 0].astype(ck.dtype))
             cv = cv.at[pages, off].set(v[:, 0].astype(cv.dtype))
             new_cache = {"k": ck, "v": cv}
-        if cfg.attn_impl == "pallas":
+        if use_pallas(cfg):
             from repro.distributed.sharding import current_kernel_mesh
             from repro.kernels import ops as kops
             out = kops.paged_decode_attention(q[:, 0], ck, cv, page_table,
@@ -271,7 +271,7 @@ def attention_block(
             cv = cv.at[b, cache_pos].set(v[:, 0].astype(cv.dtype))
         T = ck.shape[1]
         new_cache = {"k": ck, "v": cv}
-        if cfg.attn_impl == "pallas" and S == 1:
+        if use_pallas(cfg) and S == 1:
             # decode: flash-decoding kernel over the cache (scalar or
             # per-sequence [B] positions; under a ShardingPlan the wrapper
             # shard_maps the kernel over the mesh's 'model' axis)
@@ -312,7 +312,7 @@ def attention_block(
                 k, P("data", None, None, None))
             v = jax.lax.with_sharding_constraint(
                 v, P("data", None, None, None))
-        if cfg.attn_impl == "pallas" and causal and S > 1:
+        if use_pallas(cfg) and causal and S > 1:
             from repro.distributed.sharding import current_kernel_mesh
             from repro.kernels import ops as kops
             out = kops.flash_attention(

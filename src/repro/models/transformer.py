@@ -44,11 +44,17 @@ def _dtype(cfg: ModelConfig, override=None):
 # ---------------------------------------------------------------------------
 
 def _stack(pf_factory, n: int, make_one):
-    """Build n copies of a param subtree and stack leaves on a leading axis."""
-    trees = [make_one(pf_factory(i)) for i in range(n)]
-    if trees[0] is None:
-        return None
-    return jax.tree.map(lambda *ls: _stack_leaves(ls), *trees)
+    """Build n copies of a param subtree and stack leaves on a leading axis.
+
+    Concrete params come from one program vmapped over the layer index —
+    the same values as building each layer alone, but one layer's worth
+    of operations to trace and compile, not ``n``."""
+    if pf_factory(0).abstract:
+        trees = [make_one(pf_factory(i)) for i in range(n)]
+        if trees[0] is None:
+            return None
+        return jax.tree.map(lambda *ls: _stack_leaves(ls), *trees)
+    return jax.vmap(lambda i: make_one(pf_factory(i)))(jnp.arange(n))
 
 
 def _stack_leaves(leaves):

@@ -107,6 +107,12 @@ def sharded_serve_fns(model: Model, pool, plan: ShardingPlan,
         def wrapped(*args):
             with use_kernel_mesh(plan.mesh):
                 return fn(*args)
+
+        def lower(*args):
+            # the served program as the engine runs it, for inspection
+            with use_kernel_mesh(plan.mesh):
+                return fn.lower(*args)
+        wrapped.lower = lower
         return wrapped
 
     if with_adapters:

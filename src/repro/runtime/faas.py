@@ -71,6 +71,17 @@ def _engine_key(fn_name: str, event: dict) -> tuple:
     return (fn_name, tuple(sorted((event or {}).items())))
 
 
+def _zero_params(model, plan: Optional[ShardingPlan]):
+    """Zero-filled params for pre-warm compiles, each leaf created in its
+    final placement (a model sharded over several chips is never
+    materialized whole on one)."""
+    abstract = model.init_params(abstract=True)
+    if plan is None:
+        return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), abstract)
+    return jax.tree.map(lambda s, sh: jnp.zeros(s.shape, s.dtype, device=sh),
+                        abstract, plan.param_shardings(model))
+
+
 @dataclasses.dataclass
 class _WarmEngine:
     engine: ContinuousBatchingEngine
@@ -595,14 +606,6 @@ class FaaSRuntime:
         bps = -(-self.max_len // self.page_size)
         prefill_len = bps * self.page_size if paged else self.max_len
         keys = []
-
-        def zero_params(plan):
-            params = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
-                                  model.init_params(abstract=True))
-            if plan is not None:
-                params = jax.device_put(params, plan.param_shardings(model))
-            return params
-
         for inst in self.instances:
             prefill_fn, _, decode_fn = self._serve_fns_for(fn.name, inst)
             kp = (id(model), "prefill", inst.idx, 1, seq, self.max_len)
@@ -610,7 +613,7 @@ class FaaSRuntime:
                   self.max_len)
 
             def warm_prefill(inst=inst, prefill_fn=prefill_fn):
-                params = zero_params(inst.plan)
+                params = _zero_params(model, inst.plan)
                 inputs = {"tokens": jnp.zeros((1, seq), jnp.int32)}
                 cache = model.make_cache(1, prefill_len)
                 if inst.plan is not None:
@@ -620,7 +623,7 @@ class FaaSRuntime:
                 return prefill_fn
 
             def warm_decode(inst=inst, decode_fn=decode_fn):
-                params = zero_params(inst.plan)
+                params = _zero_params(model, inst.plan)
                 toks = jnp.zeros((self.n_slots, 1), jnp.int32)
                 pos = jnp.zeros((self.n_slots,), jnp.int32)
                 if paged:
@@ -662,14 +665,6 @@ class FaaSRuntime:
         bps = -(-self.max_len // ps)
         padded = bps * ps
         keys = []
-
-        def zero_params(plan):
-            params = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
-                                  model.init_params(abstract=True))
-            if plan is not None:
-                params = jax.device_put(params, plan.param_shardings(model))
-            return params
-
         for inst in self.instances:
             prefill_from = self._serve_fns_for(fn.name, inst)[1]
             if prefill_from is None:
@@ -680,7 +675,7 @@ class FaaSRuntime:
                        self.max_len)
 
                 def warm(inst=inst, prefill_from=prefill_from, slen=slen):
-                    params = zero_params(inst.plan)
+                    params = _zero_params(model, inst.plan)
                     cache = model.make_cache(1, padded)
                     if inst.plan is not None:
                         cache = jax.device_put(

@@ -1,12 +1,42 @@
-"""Small shared utilities: pytree paths, byte accounting, dtype helpers."""
+"""Small shared utilities: pytree paths, byte accounting, dtype helpers,
+and the persistent compile cache of the entry points."""
 
 from __future__ import annotations
 
+import os
+import pathlib
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+# the checkout: src/repro/utils.py -> repo root
+_REPO_ROOT = pathlib.Path(__file__).resolve().parents[2]
+
+
+def enable_compile_cache() -> str | None:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Entry points call this before their first compile.  A set
+    ``$JAX_COMPILATION_CACHE_DIR`` wins (JAX reads it itself); otherwise
+    the cache sits at ``.jax_cache`` in the checkout — a fixed path, so a
+    later run of the same checkout finds what earlier runs compiled.
+    Every executable is cached however fast it compiled: the per-shape
+    prefill and decode programs are what a second run saves.  On the CPU
+    backend nothing is enabled (returns None): its cached executables
+    are tied to the host's CPU features, and CPU rehearsals compile fast.
+    """
+    if jax.default_backend() == "cpu":
+        return None
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(_REPO_ROOT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return path
 
 
 def path_str(path) -> str:

@@ -103,6 +103,20 @@ def test_decode_attention_any_length(length):
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
 
 
+def test_decode_attention_per_sequence_lengths():
+    """Scalar-prefetched lengths: every sequence of the batch masks at its
+    own length (smollm-135m decode widths: 9 heads over 3 KV heads)."""
+    B, H, KV, T, d = 4, 9, 3, 512, 64
+    ks = jax.random.split(jax.random.fold_in(RNG, 41), 3)
+    q = jax.random.normal(ks[0], (B, H, d))
+    k = jax.random.normal(ks[1], (B, KV, T, d))
+    v = jax.random.normal(ks[2], (B, KV, T, d))
+    lengths = jnp.asarray([1, 130, T - 1, T], jnp.int32)
+    out = decode_attention(q, k, v, lengths, block_k=128)
+    want = ref.decode_attention_ref(q, k, v, lengths)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
+
+
 # ---------------------------------------------------------------------------
 # paged decode attention
 # ---------------------------------------------------------------------------
@@ -220,6 +234,29 @@ def test_paged_decode_dequant_close_to_fp():
     quant = ops.paged_decode_attention(q, kq, vq, pt, lengths,
                                        k_scales=ks, v_scales=vs)
     np.testing.assert_allclose(np.asarray(quant), np.asarray(fp), atol=3e-2)
+
+
+def test_paged_decode_dequant_unit_scale_axis():
+    """The [P, KV, 1, ps] scale blocks (a unit axis so the TPU tiling takes
+    them) must steer each page's scales like its values: scales that differ
+    per page, row and head, at smollm-135m decode widths."""
+    from repro.models.quant import quantize_rows
+
+    B, H, KV, ps, NB, d = 3, 9, 3, 16, 4, 64
+    kp, vp, pt = _page_arena(jax.random.fold_in(RNG, 43), B, KV, d, ps, NB,
+                             B * NB + 1)
+    rows = jax.random.uniform(jax.random.fold_in(RNG, 47),
+                              kp.shape[:-1] + (1,), minval=0.1, maxval=4.0)
+    kq, ks = quantize_rows(kp * rows)
+    vq, vs = quantize_rows(vp / rows)
+    q = jax.random.normal(jax.random.fold_in(RNG, 53), (B, H, d))
+    lengths = jnp.asarray([NB * ps, ps + 5, 1], jnp.int32)
+    out = ops.paged_decode_attention(q, kq, vq, pt, lengths,
+                                     k_scales=ks, v_scales=vs)
+    want = ref.paged_decode_attention_ref(q, kq, vq, pt, lengths,
+                                          k_scales=ks, v_scales=vs)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
 
 
 def test_paged_decode_scales_require_pair():
@@ -356,3 +393,24 @@ def test_ops_fallback_on_odd_shapes():
     out = ops.flash_attention(q, k, v)
     want = ref.flash_attention_ref(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
+
+
+@pytest.mark.parametrize("S", [130, 200])
+def test_ops_flash_unaligned_length(S):
+    """Lengths with no power-of-two tile run on a legal block (a multiple
+    of 8 that divides S, else the whole axis) and match the oracle."""
+    ks = jax.random.split(jax.random.fold_in(RNG, S), 3)
+    q = jax.random.normal(ks[0], (1, 9, S, 64))
+    k = jax.random.normal(ks[1], (1, 3, S, 64))
+    v = jax.random.normal(ks[2], (1, 3, S, 64))
+    out = ops.flash_attention(q, k, v)
+    want = ref.flash_attention_ref(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
+
+
+def test_pick_block_is_always_legal():
+    for S in range(1, 600):
+        for want in (128, 256):
+            b = ops._pick_block(S, want)
+            assert S % b == 0
+            assert b == S or (b % 8 == 0 and b <= want), (S, want, b)
