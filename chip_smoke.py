@@ -58,7 +58,8 @@ from repro.kernels import ops, ref  # noqa: E402
 from repro.models.config import reduced  # noqa: E402
 from repro.models.quant import quantize_rows  # noqa: E402
 from repro.models.registry import get_config, get_model  # noqa: E402
-from repro.runtime.continuous import sharded_serve_fns  # noqa: E402
+from repro.runtime.continuous import (  # noqa: E402
+    serve_fns, sharded_serve_fns)
 from repro.runtime.faas import FaaSRuntime  # noqa: E402
 from repro.runtime.gateway import InvocationRequest  # noqa: E402
 from repro.runtime.kv_pool import PagedKVCachePool  # noqa: E402
@@ -320,10 +321,7 @@ def teacher_forced(model, params, prefill, decode, prompt, forced,
 
 def _plain_serve_fns(model, page_size: int):
     """One-device prefill and paged decode, jitted as the runtime does."""
-    prefill = jax.jit(lambda p, i, c: model.prefill(p, i, c))
-    decode = jax.jit(
-        lambda p, c, t, pos, pt: model.decode_step_paged(
-            p, c, {"tokens": t}, pos, pt, page_size), donate_argnums=(1,))
+    prefill, _, decode = serve_fns(model, True, page_size)
     return prefill, decode
 
 

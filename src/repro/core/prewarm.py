@@ -14,11 +14,16 @@ eagerly compiling everything (the strawman's 1.12 GB / 3 s problem).
 
 The loading *policy* (§5.1) also carries over: a worker pre-warms the
 executables of exactly the functions currently cached in its host pool.
+
+Compile accounting lives here too: ``CacheStats`` counts this module's own
+AOT compiles, and ``compile_counts`` every trace, lowering and backend
+compile JAX reports in the process (a fork's per-call ``jit`` included).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import threading
 import time
 from typing import Any, Callable, Optional
 
@@ -30,6 +35,43 @@ class CacheStats:
     hits: int = 0
     misses: int = 0
     compile_s: float = 0.0
+
+
+# JAX's duration events for the three stages of turning a call into an
+# executable; each carries the traced function's name as ``fun_name``
+COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                  "/jax/core/compile/jaxpr_to_mlir_module_duration",
+                  "/jax/core/compile/backend_compile_duration")
+_compiles: dict = {}                  # (event, fun_name) -> [count, seconds]
+_compiles_lock = threading.Lock()
+_compiles_listening = False
+
+
+def _on_compile_event(event: str, duration: float, **kwargs) -> None:
+    if event not in COMPILE_EVENTS:
+        return
+    key = (event, str(kwargs.get("fun_name", "")))
+    with _compiles_lock:
+        c = _compiles.setdefault(key, [0, 0.0])
+        c[0] += 1
+        c[1] += float(duration)
+
+
+def listen_for_compiles() -> None:
+    """Register the process-wide compile listener (once)."""
+    global _compiles_listening
+    with _compiles_lock:
+        if _compiles_listening:
+            return
+        _compiles_listening = True
+    jax.monitoring.register_event_duration_secs_listener(_on_compile_event)
+
+
+def compile_counts() -> dict:
+    """``{(event, fun_name): (count, seconds)}`` of every compile event
+    since ``listen_for_compiles`` was first called."""
+    with _compiles_lock:
+        return {k: (c[0], c[1]) for k, c in _compiles.items()}
 
 
 class ExecutableCache:

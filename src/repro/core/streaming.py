@@ -13,6 +13,15 @@ transfer/compute overlap; on CPU it still validates the schedule and the
 sync correctness (results must equal the monolithic prefill bit-for-bit —
 tested).  The per-layer block function is jitted ONCE and reused for every
 layer: the executable-sharing analogue of TIDAL's kernel dedup.
+
+Profiler spans (host side, carrying the forked request's ``req_id``):
+``tidal.stream.upload`` per slice on the streamer thread,
+``tidal.stream.wait`` where a consumer blocks on a slice the streamer
+thread has not handed over yet, ``tidal.fork.assemble`` where the first
+full params are stacked, and ``tidal.prefill.layer`` per layer of the
+streamed prefill.  An upload span ends when its transfer is enqueued, not
+when it lands: the device's wait for a slice in flight shows as device
+idle time under the consumer's spans, not as a wait span.
 """
 
 from __future__ import annotations
@@ -58,7 +67,7 @@ class WeightStreamer:
     def __init__(self, entries: list, resident: dict, dynamic: dict,
                  record_order: bool = True, fetch_retries: int = 2,
                  retry_backoff_s: float = 0.005,
-                 max_backoff_s: float = 0.25):
+                 max_backoff_s: float = 0.25, req_id: int = -1):
         """resident/dynamic: {path: device array} available immediately.
 
         A slice fetch that raises is retried up to ``fetch_retries`` times
@@ -74,6 +83,7 @@ class WeightStreamer:
         self.retry_backoff_s = float(retry_backoff_s)
         self.max_backoff_s = float(max_backoff_s)
         self.retries_used = 0
+        self.req_id = req_id             # the request whose fork streams
         self._arrays: dict = {}
         self._events: dict = {e.key: threading.Event() for e in entries}
         self.completed_order: list = [] if record_order else None
@@ -87,6 +97,14 @@ class WeightStreamer:
 
     def _fetch_one(self, e: StreamEntry):
         """Fetch + upload one slice, retrying transient failures."""
+        with jax.profiler.TraceAnnotation(
+                "tidal.stream.upload", req_id=self.req_id,
+                layer=_layer(e.key)) as span:
+            arr = self._upload(e)
+            span.set_metadata(nbytes=int(arr.nbytes))
+        return arr
+
+    def _upload(self, e: StreamEntry):
         delay = self.retry_backoff_s
         attempt = 0
         while True:
@@ -130,7 +148,11 @@ class WeightStreamer:
         ev = self._events.get(key)
         if ev is None:
             raise KeyError(f"{key} neither resident, dynamic nor streamed")
-        ev.wait()
+        if not ev.is_set():
+            with jax.profiler.TraceAnnotation(
+                    "tidal.stream.wait", req_id=self.req_id,
+                    layer=_layer(key)):
+                ev.wait()
         # a fetch failure sets every event so no consumer hangs: slices that
         # landed before the failure stay servable, the rest raise
         if key in self._arrays:
@@ -150,6 +172,11 @@ class WeightStreamer:
         into a full leaf); their device buffers free with the last use."""
         for key in keys:
             self._arrays.pop(key, None)
+
+
+def _layer(key: tuple) -> int:
+    """The layer of a stream key's slice; -1 for a whole leaf."""
+    return int(key[1][0]) if key[1] else -1
 
 
 class ForkSession:
@@ -193,19 +220,24 @@ class ForkSession:
         released leaf by leaf: a forked model then occupies its size once
         on the device, not twice."""
         if self._params is None:
-            specs = self.model.init_params(abstract=True)
-            flat, treedef = jax.tree_util.tree_flatten_with_path(specs)
-            paths = [path_str(p) for p, _ in flat]
-            leaves = []
-            for path in paths:
-                leaves.append(self.leaf(path))
-                kind = self.leaf_index.get(path, ("whole",))
-                layers = [()] if kind[0] == "whole" else [
-                    (l,) for l in range(kind[1])]
-                self.streamer.release([(path, idx) for idx in layers])
-            self._params = jax.tree_util.tree_unflatten(treedef, leaves)
-            self._leaves = dict(zip(paths, leaves))
+            with jax.profiler.TraceAnnotation(
+                    "tidal.fork.assemble", req_id=self.streamer.req_id):
+                self._assemble()
         return self._params
+
+    def _assemble(self) -> None:
+        specs = self.model.init_params(abstract=True)
+        flat, treedef = jax.tree_util.tree_flatten_with_path(specs)
+        paths = [path_str(p) for p, _ in flat]
+        leaves = []
+        for path in paths:
+            leaves.append(self.leaf(path))
+            kind = self.leaf_index.get(path, ("whole",))
+            layers = [()] if kind[0] == "whole" else [
+                (l,) for l in range(kind[1])]
+            self.streamer.release([(path, idx) for idx in layers])
+        self._params = jax.tree_util.tree_unflatten(treedef, leaves)
+        self._leaves = dict(zip(paths, leaves))
 
 
 # ---------------------------------------------------------------------------
@@ -279,8 +311,9 @@ def streamed_prefill(session: ForkSession, inputs: dict, cache, offset: int = 0)
     off = jnp.asarray(offset, jnp.int32)
     positions = jnp.broadcast_to(off + jnp.arange(S)[None, :], (B, S))
 
+    # a fresh jit per call: every fork traces and lowers it again
     @jax.jit
-    def block_fn(bp, x, layer_cache):
+    def serve_prefill_layer(bp, x, layer_cache):
         return transformer._dense_block(bp, x, cfg, positions, layer_cache,
                                         off)
 
@@ -288,10 +321,11 @@ def streamed_prefill(session: ForkSession, inputs: dict, cache, offset: int = 0)
                      scale_by_dim=cfg.scale_embed)
     new_layer_caches = []
     for l in range(cfg.n_layers):
-        leaves = [session.block_slice(p, l) for p in block_paths]
-        bp = jax.tree_util.tree_unflatten(blocks_treedef, leaves)
-        layer_cache = jax.tree.map(lambda t: t[l], cache)
-        x, new_c, _ = block_fn(bp, x, layer_cache)
+        with jax.profiler.TraceAnnotation("tidal.prefill.layer", layer=l):
+            leaves = [session.block_slice(p, l) for p in block_paths]
+            bp = jax.tree_util.tree_unflatten(blocks_treedef, leaves)
+            layer_cache = jax.tree.map(lambda t: t[l], cache)
+            x, new_c, _ = serve_prefill_layer(bp, x, layer_cache)
         new_layer_caches.append(new_c)
 
     x = rmsnorm(x[:, -1:, :], session.leaf("final_norm"), cfg.norm_eps)

@@ -24,7 +24,6 @@ Per registered function the server keeps:
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Optional
 
 import jax
@@ -47,7 +46,6 @@ class ForkStats:
     reused_bytes: int = 0        # shared device buffers (resident prefix)
     streamed_bytes: int = 0      # async host->device in access order
     dynamic_bytes: int = 0       # replayed request-specific weights
-    fork_s: float = 0.0
     new_dynamic: tuple = ()
 
 
@@ -217,7 +215,7 @@ class TemplateServer:
 
     # ------------------------------------------------------------------
     def fork(self, fn_name: str, event: dict,
-             plan: Optional[ShardingPlan] = None
+             plan: Optional[ShardingPlan] = None, req_id: int = -1
              ) -> tuple[ForkSession, ForkStats]:
         """Adaptive state forking for one invocation.
 
@@ -225,8 +223,19 @@ class TemplateServer:
         placed tensor-parallel on the plan's mesh: resident buffers are
         shared (re-placed once if the fork targets a different mesh slice),
         dynamic replays upload sharded, and the access-order stream lands
-        each slice directly in its NamedSharding device buffers."""
-        t0 = time.perf_counter()
+        each slice directly in its NamedSharding device buffers.
+
+        ``req_id`` names the invocation the fork serves in the profiler
+        spans of the fork and of its weight stream (-1: none)."""
+        with jax.profiler.TraceAnnotation("tidal.fork",
+                                          req_id=req_id) as span:
+            session, stats = self._fork(fn_name, event, plan, req_id)
+            span.set_metadata(streamed_bytes=stats.streamed_bytes)
+        return session, stats
+
+    def _fork(self, fn_name: str, event: dict,
+              plan: Optional[ShardingPlan], req_id: int
+              ) -> tuple[ForkSession, ForkStats]:
         plan = plan or self.plan
         fn = self._functions[fn_name]
         template = self.templates[fn_name]
@@ -294,10 +303,9 @@ class TemplateServer:
                     sharding=_shard_for(path, True)))
                 stats.streamed_bytes += src[layer].nbytes
 
-        streamer = WeightStreamer(entries, resident, dynamic).start()
-        session = ForkSession(fn.model, streamer, kinds)
-        stats.fork_s = time.perf_counter() - t0
-        return session, stats
+        streamer = WeightStreamer(entries, resident, dynamic,
+                                  req_id=req_id).start()
+        return ForkSession(fn.model, streamer, kinds), stats
 
     # ------------------------------------------------------------------
     def observe_ttft(self, fn_name: str, ttft_s: float) -> None:

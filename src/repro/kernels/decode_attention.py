@@ -124,5 +124,6 @@ def decode_attention(q, k, v, length, *, block_k: int = 256,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, d), q.dtype),
         interpret=interpret,
+        name="decode_attention",
     )(lengths, qg, k, v)
     return out.reshape(B, H, d)

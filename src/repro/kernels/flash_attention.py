@@ -131,4 +131,5 @@ def flash_attention(q, k, v, *, causal: bool = True, softcap: float = 0.0,
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

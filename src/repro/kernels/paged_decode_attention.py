@@ -207,5 +207,6 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, d), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(*operands)
     return out.reshape(B, H, d)

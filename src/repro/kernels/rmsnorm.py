@@ -50,6 +50,7 @@ def rmsnorm(x, scale, eps: float = 1e-6, block_rows: int = 128,
         out_specs=pl.BlockSpec((br, d), lambda r: (r, 0)),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
         interpret=interpret,
+        name="rmsnorm",
     )(x2, scale)
     if pad:
         out = out[:rows]

@@ -109,5 +109,6 @@ def ssd_scan(xb, Bm, Cm, ld, chunk: int = 128,
         ],
         scratch_shapes=[pltpu.VMEM((dh, ds), jnp.float32)],
         interpret=interpret,
+        name="ssd_scan",
     )(xb, Bm, Cm, ld)
     return y, h

@@ -52,6 +52,13 @@ advances every slot's state and cannot be null-masked.
 Greedy decoding is bit-identical to the sequential ``Engine.generate``
 per request (tested): the per-slot position vector reproduces exactly the
 positions, cache writes and attention masks of an isolated batch-1 run.
+
+Request ids are unique in the process (``new_req_id``), so a gateway can
+name a request before it forks the engine that will serve it.  Each step
+is a ``tidal.step`` profiler span (decoding slots ``live`` of ``rows``,
+``admitted``, ``chunked`` prompt tokens) holding ``tidal.prefill``,
+``tidal.sample_first``, ``tidal.decode.dispatch``, ``tidal.decode.sync``
+and ``tidal.emit`` spans; see PERF.md for what reads each.
 """
 
 from __future__ import annotations
@@ -59,6 +66,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import itertools
 import time
 from typing import Any, Callable, Optional
 
@@ -76,6 +84,60 @@ from repro.runtime.kv_pool import (KVCachePool, PagedKVCachePool,
                                    PoolExhausted)
 
 
+def _serve_bodies(model: Model, paged: bool, page_size: int,
+                  with_adapters: bool = False) -> tuple:
+    """The three serve entry points as plain functions, named so their
+    device programs read ``jit_serve_prefill``, ``jit_serve_prefill_from``
+    and ``jit_serve_decode`` (``serve_prefill_from`` is None without a
+    paged arena).  ``with_adapters`` appends ``(adapter_bank,
+    adapter_ids)`` arguments to each."""
+    if with_adapters:
+        if not paged:
+            raise ValueError("adapter banks serve over the paged arena only")
+
+        def serve_prefill(p, inputs, cache, bank, aids):
+            return model.prefill(p, inputs, cache, adapter_bank=bank,
+                                 adapter_ids=aids)
+
+        def serve_prefill_from(p, toks, cache, off, bank, aids):
+            return model.prefill_from(p, {"tokens": toks}, cache, off,
+                                      adapter_bank=bank, adapter_ids=aids)
+
+        def serve_decode(p, cache, toks, pos, pt, bank, aids):
+            return model.decode_step_paged(
+                p, cache, {"tokens": toks}, pos, pt, page_size,
+                adapter_bank=bank, adapter_ids=aids)
+        return serve_prefill, serve_prefill_from, serve_decode
+
+    def serve_prefill(p, inputs, cache):
+        return model.prefill(p, inputs, cache)
+
+    if not paged:
+        def serve_decode(p, cache, toks, pos):
+            return model.decode_step(p, cache, {"tokens": toks}, pos)
+        return serve_prefill, None, serve_decode
+
+    def serve_prefill_from(p, toks, cache, off):
+        return model.prefill_from(p, {"tokens": toks}, cache, off)
+
+    def serve_decode(p, cache, toks, pos, pt):
+        return model.decode_step_paged(p, cache, {"tokens": toks}, pos, pt,
+                                       page_size)
+    return serve_prefill, serve_prefill_from, serve_decode
+
+
+def serve_fns(model: Model, paged: bool, page_size: int,
+              donate_cache: bool = True, with_adapters: bool = False):
+    """jit'd ``(prefill_fn, prefill_from_fn, decode_fn)`` serve entry
+    points on one device (see ``_serve_bodies``); the decode step donates
+    the KV arena unless ``donate_cache`` is False."""
+    prefill, prefill_from, decode = _serve_bodies(model, paged, page_size,
+                                                  with_adapters)
+    return (jax.jit(prefill),
+            jax.jit(prefill_from) if prefill_from is not None else None,
+            jax.jit(decode, donate_argnums=(1,) if donate_cache else ()))
+
+
 def sharded_serve_fns(model: Model, pool, plan: ShardingPlan,
                       donate_cache: bool = True,
                       with_adapters: bool = False):
@@ -85,8 +147,8 @@ def sharded_serve_fns(model: Model, pool, plan: ShardingPlan,
     across donated decode steps, and GSPMD partitions the dense/paged
     attention paths.  Tokens / positions / page tables / logits are
     replicated (host-driven control state).  ``prefill_from_fn`` is the
-    suffix-only entry point for prefix KV reuse (None for families without
-    one).  Every entry point is called (and therefore traced) under
+    suffix-only entry point for prefix KV reuse (None without a paged
+    pool).  Every entry point is called (and therefore traced) under
     ``use_kernel_mesh(plan.mesh)`` so ``attn_impl='pallas'`` shard_maps
     the attention kernels over the 'model' axis instead of silently
     falling back to the XLA reference inside the partitioned jit.
@@ -97,8 +159,9 @@ def sharded_serve_fns(model: Model, pool, plan: ShardingPlan,
     rep = plan.replicated
     pshard = plan.param_shardings(model)
     paged = isinstance(pool, PagedKVCachePool)
-    if with_adapters and not paged:
-        raise ValueError("adapter banks serve over the paged arena only")
+    prefill, prefill_from, decode = _serve_bodies(
+        model, paged, pool.page_size if paged else 0, with_adapters)
+    extra = (rep, rep) if with_adapters else ()
     prefill_len = pool.padded_len if paged else pool.max_len
     pc_shard = plan.cache_shardings(
         model, model.make_cache(1, prefill_len, abstract=True))
@@ -115,63 +178,34 @@ def sharded_serve_fns(model: Model, pool, plan: ShardingPlan,
         wrapped.lower = lower
         return wrapped
 
-    if with_adapters:
-        prefill_fn = _km(jax.jit(
-            lambda p, inputs, cache, bank, aids: model.prefill(
-                p, inputs, cache, adapter_bank=bank, adapter_ids=aids),
-            in_shardings=(pshard, rep, pc_shard, rep, rep),
-            out_shardings=(rep, pc_shard)))
-    else:
-        prefill_fn = _km(jax.jit(
-            lambda p, inputs, cache: model.prefill(p, inputs, cache),
-            in_shardings=(pshard, rep, pc_shard),
-            out_shardings=(rep, pc_shard)))
+    prefill_fn = _km(jax.jit(prefill,
+                             in_shardings=(pshard, rep, pc_shard) + extra,
+                             out_shardings=(rep, pc_shard)))
     prefill_from_fn = None
-    if model.supports_paged_kv:
-        if with_adapters:
-            prefill_from_fn = _km(jax.jit(
-                lambda p, toks, cache, off, bank, aids: model.prefill_from(
-                    p, {"tokens": toks}, cache, off,
-                    adapter_bank=bank, adapter_ids=aids),
-                in_shardings=(pshard, rep, pc_shard, rep, rep, rep),
-                out_shardings=(rep, pc_shard)))
-        else:
-            prefill_from_fn = _km(jax.jit(
-                lambda p, toks, cache, off: model.prefill_from(
-                    p, {"tokens": toks}, cache, off),
-                in_shardings=(pshard, rep, pc_shard, rep),
-                out_shardings=(rep, pc_shard)))
+    if prefill_from is not None:
+        prefill_from_fn = _km(jax.jit(
+            prefill_from, in_shardings=(pshard, rep, pc_shard, rep) + extra,
+            out_shardings=(rep, pc_shard)))
     if paged:
-        ps = pool.page_size
         dshard = plan.paged_cache_shardings(model, pool.cache)
-        if with_adapters:
-            decode_fn = _km(jax.jit(
-                lambda p, cache, toks, pos, pt, bank, aids:
-                model.decode_step_paged(
-                    p, cache, {"tokens": toks}, pos, pt, ps,
-                    adapter_bank=bank, adapter_ids=aids),
-                in_shardings=(pshard, dshard, rep, rep, rep, rep, rep),
-                out_shardings=(rep, dshard),
-                donate_argnums=(1,) if donate_cache else ()))
-        else:
-            decode_fn = _km(jax.jit(
-                lambda p, cache, toks, pos, pt: model.decode_step_paged(
-                    p, cache, {"tokens": toks}, pos, pt, ps),
-                in_shardings=(pshard, dshard, rep, rep, rep),
-                out_shardings=(rep, dshard),
-                donate_argnums=(1,) if donate_cache else ()))
+        control = (rep, rep, rep)            # tokens, positions, page table
     else:
         dshard = plan.cache_shardings(model, pool.cache)
-        decode_fn = _km(jax.jit(
-            lambda p, cache, toks, pos: model.decode_step(
-                p, cache, {"tokens": toks}, pos),
-            in_shardings=(pshard, dshard, rep, rep),
-            out_shardings=(rep, dshard),
-            donate_argnums=(1,) if donate_cache else ()))
+        control = (rep, rep)
+    decode_fn = _km(jax.jit(
+        decode, in_shardings=(pshard, dshard) + control + extra,
+        out_shardings=(rep, dshard),
+        donate_argnums=(1,) if donate_cache else ()))
     return prefill_fn, prefill_from_fn, decode_fn
 
 
 _UNMATCHED = object()                # prefix match not yet attempted
+_req_ids = itertools.count()
+
+
+def new_req_id() -> int:
+    """A request id no other request of this process carries."""
+    return next(_req_ids)
 
 
 @dataclasses.dataclass
@@ -290,7 +324,7 @@ class ContinuousBatchingEngine:
         self.queue: collections.deque = collections.deque()
         self.active: dict = {}                       # slot -> _Active
         self.results: dict = {}                      # req_id -> RequestOutput
-        self._next_id = 0
+        self.n_steps = 0                             # step() calls so far
         if plan is not None:
             self._param_shardings = plan.param_shardings(model)
             prefill_len = (self.pool.padded_len if self.paged
@@ -307,42 +341,12 @@ class ContinuousBatchingEngine:
                 default_p, default_pf, default_d = sharded_serve_fns(
                     model, self.pool, plan, donate_cache=donate_cache,
                     with_adapters=adapter_bank is not None)
-            elif adapter_bank is not None:
-                default_p = jax.jit(
-                    lambda p, inputs, cache, bank, aids: model.prefill(
-                        p, inputs, cache, adapter_bank=bank,
-                        adapter_ids=aids))
-                default_pf = jax.jit(
-                    lambda p, toks, cache, off, bank, aids:
-                    model.prefill_from(
-                        p, {"tokens": toks}, cache, off,
-                        adapter_bank=bank, adapter_ids=aids))
-                default_d = jax.jit(
-                    lambda p, cache, toks, pos, pt, bank, aids:
-                    model.decode_step_paged(
-                        p, cache, {"tokens": toks}, pos, pt,
-                        self.pool.page_size,
-                        adapter_bank=bank, adapter_ids=aids),
-                    donate_argnums=(1,) if donate_cache else ())
             else:
-                default_p = jax.jit(
-                    lambda p, inputs, cache: model.prefill(p, inputs, cache))
-                default_pf = None
-                if self.paged:
-                    default_pf = jax.jit(
-                        lambda p, toks, cache, off: model.prefill_from(
-                            p, {"tokens": toks}, cache, off))
-                    default_d = jax.jit(
-                        lambda p, cache, toks, pos, pt:
-                        model.decode_step_paged(
-                            p, cache, {"tokens": toks}, pos, pt,
-                            self.pool.page_size),
-                        donate_argnums=(1,) if donate_cache else ())
-                else:
-                    default_d = jax.jit(
-                        lambda p, cache, toks, pos: model.decode_step(
-                            p, cache, {"tokens": toks}, pos),
-                        donate_argnums=(1,) if donate_cache else ())
+                default_p, default_pf, default_d = serve_fns(
+                    model, self.paged,
+                    self.pool.page_size if self.paged else 0,
+                    donate_cache=donate_cache,
+                    with_adapters=adapter_bank is not None)
             prefill_fn = prefill_fn or default_p
             prefill_from_fn = prefill_from_fn or default_pf
             decode_fn = decode_fn or default_d
@@ -373,6 +377,7 @@ class ContinuousBatchingEngine:
         # base-model requests gather a zero delta)
         self._aid = np.zeros((n_slots,), np.int32)
         self._step_tokens = 0            # work done by the last step()
+        self._step_counts = (0, 0, 0)    # its (live, admitted, chunked)
 
     # ------------------------------------------------------------------
     def params(self):
@@ -407,8 +412,9 @@ class ContinuousBatchingEngine:
                seed: int = 0, deadline_s: Optional[float] = None,
                priority: int = 0,
                token_cb: Optional[Callable] = None,
-               adapter_id: int = 0) -> int:
-        """Enqueue one request.  ``submit_s`` backdates the arrival stamp so
+               adapter_id: int = 0, req_id: Optional[int] = None) -> int:
+        """Enqueue one request; returns its id (``req_id``, or a fresh one
+        from ``new_req_id``).  ``submit_s`` backdates the arrival stamp so
         work done on the request's behalf before enqueueing (forking this
         engine's session, say) counts toward its TTFT.  ``temperature=0``
         decodes greedily (the bit-parity reference); otherwise tokens are
@@ -447,8 +453,7 @@ class ContinuousBatchingEngine:
                 raise ValueError(
                     f"request needs {need} KV pages but the arena has only "
                     f"{self.pool.n_pages - 1} allocatable pages")
-        rid = self._next_id
-        self._next_id += 1
+        rid = new_req_id() if req_id is None else req_id
         self.queue.append(Request(rid, prompt, max_new_tokens,
                                   submit_s or time.perf_counter(),
                                   temperature=temperature, top_p=top_p,
@@ -581,12 +586,14 @@ class ContinuousBatchingEngine:
                 else contextlib.nullcontext())
 
     def _sample_first(self, req: Request, logits) -> int:
-        if req.temperature <= 0:
-            tok = sample_greedy(logits)
-            tok.block_until_ready()
-            return int(tok[0])
-        return sample_token(np.asarray(logits[0]), req.temperature,
-                            req.top_p, req.seed, 0)
+        with jax.profiler.TraceAnnotation("tidal.sample_first",
+                                          req_id=req.req_id):
+            if req.temperature <= 0:
+                tok = sample_greedy(logits)
+                tok.block_until_ready()
+                return int(tok[0])
+            return sample_token(np.asarray(logits[0]), req.temperature,
+                                req.top_p, req.seed, 0)
 
     def _admit(self, req: Request) -> None:
         # injection point BEFORE any allocation: a crash here leaves no
@@ -635,13 +642,10 @@ class ContinuousBatchingEngine:
                 self.pool.release(slot)
             raise
 
-    def _prefill_into(self, req: Request, slot: int, reuse: int) -> None:
-        """Whole-prompt (or suffix-only) prefill into an allocated slot."""
-        streamed = (self.session is not None and self._params is None
-                    and self.adapter_bank is None
-                    and supports_streamed_prefill(self.model))
-        prefill_len = (self.pool.padded_len if self.paged
-                       else self.pool.max_len)
+    def _prefill(self, req: Request, slot: int, reuse: int,
+                 streamed: bool) -> tuple:
+        """(last-token logits, filled batch-1 dense cache) of the prompt
+        past its ``reuse`` reused tokens, which ``slot`` holds."""
         if reuse:
             # suffix-only prefill: gather the slot's pages (aliased prefix
             # + its COW partial copy) as the working dense cache, then run
@@ -650,38 +654,43 @@ class ContinuousBatchingEngine:
             suffix = jnp.asarray(req.prompt[None, reuse:])
             if streamed:
                 with self._kmesh():
-                    logits, cache = streamed_prefill(
-                        self.session, {"tokens": suffix}, cache,
-                        offset=reuse)
-            else:
-                logits, cache = self._call_prefill_from(
-                    suffix, cache, reuse, req.adapter_id)
-        else:
-            inputs = {"tokens": jnp.asarray(req.prompt[None, :])}
-            # prefill runs on a transient batch-1 dense cache either way
-            # (same executable as the dense path); paged pools then keep
-            # only the prompt's pages
-            cache = self.model.make_cache(1, prefill_len)
-            if self.plan is not None:
-                cache = jax.device_put(cache, self._prefill_cache_shardings)
-            if streamed:
-                with self._kmesh():
-                    logits, cache = streamed_prefill(self.session, inputs,
-                                                     cache)
-                if self.plan is not None:
-                    # per-block jits leave GSPMD-propagated shardings on
-                    # the filled cache; re-pin to the pool's layout so the
-                    # decode executable's in_shardings match
-                    cache = jax.device_put(cache,
-                                           self._prefill_cache_shardings)
-            else:
-                logits, cache = self._call_prefill(inputs, cache,
-                                                   req.adapter_id)
+                    return streamed_prefill(self.session, {"tokens": suffix},
+                                            cache, offset=reuse)
+            return self._call_prefill_from(suffix, cache, reuse,
+                                           req.adapter_id)
+        inputs = {"tokens": jnp.asarray(req.prompt[None, :])}
+        # prefill runs on a transient batch-1 dense cache either way (same
+        # executable as the dense path); paged pools then keep only the
+        # prompt's pages
+        cache = self.model.make_cache(
+            1, self.pool.padded_len if self.paged else self.pool.max_len)
+        if self.plan is not None:
+            cache = jax.device_put(cache, self._prefill_cache_shardings)
+        if not streamed:
+            return self._call_prefill(inputs, cache, req.adapter_id)
+        with self._kmesh():
+            logits, cache = streamed_prefill(self.session, inputs, cache)
+        if self.plan is not None:
+            # per-block jits leave GSPMD-propagated shardings on the filled
+            # cache; re-pin to the pool's layout so the decode
+            # executable's in_shardings match
+            cache = jax.device_put(cache, self._prefill_cache_shardings)
+        return logits, cache
+
+    def _prefill_into(self, req: Request, slot: int, reuse: int) -> None:
+        """Whole-prompt (or suffix-only) prefill into an allocated slot."""
+        streamed = (self.session is not None and self._params is None
+                    and self.adapter_bank is None
+                    and supports_streamed_prefill(self.model))
+        with jax.profiler.TraceAnnotation(
+                "tidal.prefill", req_id=req.req_id,
+                tokens=len(req.prompt) - reuse, streamed=int(streamed)):
+            logits, cache = self._prefill(req, slot, reuse, streamed)
         first = self._sample_first(req, logits)
         ttft = time.perf_counter() - req.submit_s
         if self.paged:
             self.pool.write_suffix(slot, cache, reuse, len(req.prompt),
-                                   owner=self._owner)
+                                   owner=self._owner, req_id=req.req_id)
             self._aid[slot] = req.adapter_id
         else:
             self.pool.write_slot(slot, cache)
@@ -737,14 +746,18 @@ class ContinuousBatchingEngine:
         streamed = (self.session is not None and self._params is None
                     and self.adapter_bank is None
                     and supports_streamed_prefill(self.model))
-        if streamed:
-            with self._kmesh():
-                logits, cache = streamed_prefill(
-                    self.session, {"tokens": toks}, cache, offset=start)
-        else:
-            logits, cache = self._call_prefill_from(
-                toks, cache, start, req.adapter_id)
-        self.pool.write_suffix(slot, cache, start, end, owner=self._owner)
+        with jax.profiler.TraceAnnotation(
+                "tidal.prefill", req_id=req.req_id, tokens=end - start,
+                streamed=int(streamed)):
+            if streamed:
+                with self._kmesh():
+                    logits, cache = streamed_prefill(
+                        self.session, {"tokens": toks}, cache, offset=start)
+            else:
+                logits, cache = self._call_prefill_from(
+                    toks, cache, start, req.adapter_id)
+        self.pool.write_suffix(slot, cache, start, end, owner=self._owner,
+                               req_id=req.req_id)
         st.streamed = st.streamed or streamed
         st.cursor = end
         if final:
@@ -798,6 +811,16 @@ class ContinuousBatchingEngine:
         decode over the slots past their prompt, retire the finished.
 
         Returns False once the engine is fully drained."""
+        self.n_steps += 1
+        self._step_counts = (0, 0, 0)
+        with jax.profiler.TraceAnnotation("tidal.step",
+                                          rows=len(self._pos)) as span:
+            alive = self._step()
+            live, admitted, chunked = self._step_counts
+            span.set_metadata(live=live, admitted=admitted, chunked=chunked)
+        return alive
+
+    def _step(self) -> bool:
         if self.queue or self.active:
             # injection point before any work or allocation this step
             fault_point("engine_step",
@@ -841,6 +864,7 @@ class ContinuousBatchingEngine:
                 budget -= n
                 chunked += n
         decoding = [s for s in self.active if not self.active[s].prefilling]
+        self._step_counts = (len(decoding), admitted, chunked)
         if decoding:
             # injection point at the decode-quantum boundary: active slots
             # hold their full reserved budgets, results are partial
@@ -908,48 +932,54 @@ class ContinuousBatchingEngine:
             for slot in decoding:
                 self.pool.ensure_len(slot, int(self._pos[slot]) + 1,
                                      owner=self._owner)
-            # the page table rides device-resident; only rows dirtied by
-            # admit/grow/retire re-upload (steady-state decode sends none).
-            # The OWNER-masked view nulls co-tenants' rows, so their slots
-            # decode as free-slot dummies — the step never reads or writes
-            # a foreign slot's pages even though it spans every slot index.
-            pt = self.pool.device_page_table(self._owner)
-            if self.adapter_bank is not None:
-                logits, self.pool.cache = self.decode_fn(
-                    self.params(), self.pool.cache, jnp.asarray(self._tok),
-                    jnp.asarray(self._pos), pt, self.adapter_bank,
-                    jnp.asarray(self._aid))
-            else:
-                logits, self.pool.cache = self.decode_fn(
-                    self.params(), self.pool.cache, jnp.asarray(self._tok),
-                    jnp.asarray(self._pos), pt)
-        else:
+        with jax.profiler.TraceAnnotation("tidal.decode.dispatch"):
+            logits = self._dispatch_decode()
+        with jax.profiler.TraceAnnotation("tidal.decode.sync"):
+            nxt = np.asarray(sample_greedy(logits))      # [n_slots]
+            sampled = [s for s in decoding
+                       if self.active[s].req.temperature > 0]
+            if sampled:
+                nxt = nxt.copy()             # jax-backed views are read-only
+                rows = np.asarray(logits)
+                for slot in sampled:
+                    st = self.active[slot]
+                    nxt[slot] = sample_token(rows[slot], st.req.temperature,
+                                             st.req.top_p, st.req.seed,
+                                             len(st.tokens))
+        with jax.profiler.TraceAnnotation("tidal.emit"):
+            for slot in decoding:
+                st = self.active[slot]
+                tok = int(nxt[slot])
+                st.tokens.append(tok)
+                self._tok[slot, 0] = tok
+                self._pos[slot] += 1
+                if st.req.token_cb is not None:
+                    st.req.token_cb(st.req.req_id, tok, len(st.tokens) - 1)
+                if len(st.tokens) >= st.req.max_new_tokens:
+                    self._retire(slot)
+        self._step_tokens = chunked + len(decoding)
+        return bool(self.queue or self.active)
+
+    def _dispatch_decode(self):
+        """Dispatch one batched decode over every slot; returns its logits
+        while the device runs on."""
+        if not self.paged:
             logits, self.pool.cache = self.decode_fn(
                 self.params(), self.pool.cache, jnp.asarray(self._tok),
                 jnp.asarray(self._pos))
-        nxt = np.asarray(sample_greedy(logits))          # [n_slots]
-        sampled = [s for s in decoding
-                   if self.active[s].req.temperature > 0]
-        if sampled:
-            nxt = nxt.copy()                 # jax-backed views are read-only
-            rows = np.asarray(logits)
-            for slot in sampled:
-                st = self.active[slot]
-                nxt[slot] = sample_token(rows[slot], st.req.temperature,
-                                         st.req.top_p, st.req.seed,
-                                         len(st.tokens))
-        for slot in decoding:
-            st = self.active[slot]
-            tok = int(nxt[slot])
-            st.tokens.append(tok)
-            self._tok[slot, 0] = tok
-            self._pos[slot] += 1
-            if st.req.token_cb is not None:
-                st.req.token_cb(st.req.req_id, tok, len(st.tokens) - 1)
-            if len(st.tokens) >= st.req.max_new_tokens:
-                self._retire(slot)
-        self._step_tokens = chunked + len(decoding)
-        return bool(self.queue or self.active)
+            return logits
+        # the page table rides device-resident; only rows dirtied by
+        # admit/grow/retire re-upload (steady-state decode sends none).
+        # The OWNER-masked view nulls co-tenants' rows, so their slots
+        # decode as free-slot dummies — the step never reads or writes a
+        # foreign slot's pages even though it spans every slot index.
+        args = (self.params(), self.pool.cache, jnp.asarray(self._tok),
+                jnp.asarray(self._pos),
+                self.pool.device_page_table(self._owner))
+        if self.adapter_bank is not None:
+            args += (self.adapter_bank, jnp.asarray(self._aid))
+        logits, self.pool.cache = self.decode_fn(*args)
+        return logits
 
     def step_n(self, n: int) -> bool:
         """Up to ``n`` steps — the gateway's scheduling quantum.  Between

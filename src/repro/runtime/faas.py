@@ -52,12 +52,13 @@ from jax.sharding import Mesh
 
 from repro.core import api as tidal
 from repro.core.api import LLMFunction
-from repro.core.prewarm import ExecutableCache, ProcessPool
+from repro.core.prewarm import (ExecutableCache, ProcessPool,
+                                compile_counts, listen_for_compiles)
 from repro.core.template_server import TemplateServer
 from repro.distributed.sharding import ShardingPlan, serving_plan
 from repro.models.adapters import check_bank_config, make_adapter_bank
 from repro.models.registry import get_smoke_model
-from repro.runtime.continuous import (ContinuousBatchingEngine,
+from repro.runtime.continuous import (ContinuousBatchingEngine, serve_fns,
                                       sharded_serve_fns)
 from repro.runtime.gateway import (InvocationGateway, InvocationHandle,
                                    InvocationRequest, SubmitResult)
@@ -138,6 +139,7 @@ class FaaSRuntime:
         self.keep_alive_s = keep_alive_s
         self.max_warm_engines = max_warm_engines
         self.prewarm = prewarm
+        listen_for_compiles()            # process-wide: stats()["compile"]
         self.exe_cache = ExecutableCache()
         self.workers = ProcessPool(pool_workers, self.exe_cache)
         self.functions: dict[str, LLMFunction] = {}
@@ -245,24 +247,9 @@ class FaaSRuntime:
                 self._serve_fns[key] = sharded_serve_fns(model, pool,
                                                          inst.plan)
             else:
-                prefill = jax.jit(
-                    lambda p, i, c, m=model: m.prefill(p, i, c))
-                prefill_from = None
-                if model.supports_paged_kv:
-                    # attention families decode against the paged arena
-                    prefill_from = jax.jit(
-                        lambda p, t, c, off, m=model: m.prefill_from(
-                            p, {"tokens": t}, c, off))
-                    decode = jax.jit(
-                        lambda p, c, t, pos, pt, m=model: m.decode_step_paged(
-                            p, c, {"tokens": t}, pos, pt, self.page_size),
-                        donate_argnums=(1,))
-                else:
-                    decode = jax.jit(
-                        lambda p, c, t, pos, m=model: m.decode_step(
-                            p, c, {"tokens": t}, pos),
-                        donate_argnums=(1,))
-                self._serve_fns[key] = (prefill, prefill_from, decode)
+                # attention families decode against the paged arena
+                self._serve_fns[key] = serve_fns(
+                    model, model.supports_paged_kv, self.page_size)
         return self._serve_fns[key]
 
     # ------------------------------------------------------------------
@@ -575,8 +562,11 @@ class FaaSRuntime:
     def stats(self) -> dict:
         """Observability snapshot: per-function service-class counters
         (cold/fork/warm admission kinds, terminal done/reuse_hits/shed/
-        failed/cancelled/rejected) with derived rates, plus the gateway's
-        supervision stats and — when attached — the control plane's."""
+        failed/cancelled/rejected) with derived rates, the gateway's
+        supervision stats, the process's compile events (``"compile"``:
+        ``{(event, fun_name): (count, seconds)}``, see
+        ``core.prewarm.compile_counts``) and — when attached — the control
+        plane's."""
         fns = {}
         for fn_name, c in self.fn_stats.items():
             d = dict(c)
@@ -589,7 +579,8 @@ class FaaSRuntime:
             if c.get("done"):
                 d["reuse_hit_rate"] = c.get("reuse_hits", 0) / c["done"]
             fns[fn_name] = d
-        out = {"functions": fns, "gateway": dict(self.gateway.stats)}
+        out = {"functions": fns, "gateway": dict(self.gateway.stats),
+               "compile": compile_counts()}
         if self.control_plane is not None:
             out["control_plane"] = dict(self.control_plane.stats)
         return out
@@ -731,7 +722,8 @@ class FaaSRuntime:
         self.functions[fn_name] = dataclasses.replace(base, name=fn_name)
         self._adapter_fns[fn_name] = (base_name, adapter, float(alpha))
 
-    def _shared_engine_for(self, fn_name: str, now: float) -> tuple:
+    def _shared_engine_for(self, fn_name: str, now: float,
+                           req_id: int = -1) -> tuple:
         """Resolve an adapter function to its base's resident engine,
         creating the engine (bank and all) on first use and loading the
         function's factors into a free bank row on its first invocation."""
@@ -744,7 +736,8 @@ class FaaSRuntime:
         if warm is None:
             kind = "fork" if base_name in self._invoked else "cold"
             model = self.functions[base_name].model
-            session, stats = self.server.fork(base_name, {}, plan=inst.plan)
+            session, stats = self.server.fork(base_name, {}, plan=inst.plan,
+                                              req_id=req_id)
             bank = make_adapter_bank(model, cfg["targets"],
                                      cfg["n_adapters"], cfg["rank"])
             engine = ContinuousBatchingEngine(
@@ -860,23 +853,31 @@ class FaaSRuntime:
         return best_any
 
     def _engine_for(self, fn_name: str, event: Optional[dict],
-                    now: float) -> tuple:
+                    now: float, req_id: int = -1) -> tuple:
         """Resolve (key, engine, kind, fork_stats) for one invocation,
-        forking a new engine when no warm one exists."""
+        forking a new engine when no warm one exists.  ``req_id`` names
+        the invocation in the fork's profiler spans
+        (``tidal.engine_for`` and below)."""
         if fn_name not in self.functions:
             raise KeyError(f"function {fn_name!r} is not deployed")
         if fn_name in self._adapter_fns:
-            return self._shared_engine_for(fn_name, now)
+            return self._shared_engine_for(fn_name, now, req_id)
         key = _engine_key(fn_name, event or {})
         warm = self._engines.get(key)
         if warm is not None:
             self._invoked.add(fn_name)
             return key, warm.engine, "warm", None
         kind = "fork" if fn_name in self._invoked else "cold"
+        with jax.profiler.TraceAnnotation("tidal.engine_for", req_id=req_id,
+                                          kind=kind):
+            return self._fork_engine(fn_name, event, now, req_id, key, kind)
+
+    def _fork_engine(self, fn_name: str, event: Optional[dict], now: float,
+                     req_id: int, key: tuple, kind: str) -> tuple:
         inst = self._pick_instance(fn_name)
         model = self.functions[fn_name].model
         session, stats = self.server.fork(fn_name, event or {},
-                                          plan=inst.plan)
+                                          plan=inst.plan, req_id=req_id)
         prefill_fn, prefill_from_fn, decode_fn = self._serve_fns_for(fn_name,
                                                                      inst)
         engine = ContinuousBatchingEngine(

@@ -55,6 +55,10 @@ pump.  Greedy results are bit-identical to the drain-to-completion path
 — the per-slot position vectors make each request's decode independent
 of batch composition — which is what lets ``submit``/``submit_many``
 stay thin compat shims over this gateway.
+
+Each ``submit`` is a ``tidal.submit`` profiler span and each engine's
+quantum a ``tidal.quantum`` span; a handle stamps the arrival of every
+token, and ``SubmitResult.token_times_s`` carries the stamps.
 """
 
 from __future__ import annotations
@@ -64,9 +68,11 @@ import threading
 import time
 from typing import Any, Optional
 
+import jax
 import numpy as np
 
 from repro.core.template_server import ForkStats
+from repro.runtime.continuous import new_req_id
 from repro.runtime.errors import (
     DeadlineExceeded,
     EngineFailure,
@@ -123,6 +129,10 @@ class SubmitResult:
     reused_prefix_len: int = 0
     status: str = DONE               # 'done' | 'cancelled' | 'failed'
     retries: int = 0                 # crash retries this ticket survived
+    # perf_counter stamp at which each token reached the handle, aligned
+    # with ``tokens``
+    token_times_s: np.ndarray = dataclasses.field(
+        default_factory=lambda: np.zeros(0))
 
 
 class InvocationHandle:
@@ -155,6 +165,7 @@ class InvocationHandle:
         self.browned_out = False         # max_new clamped at admission
         self._state = QUEUED
         self._tokens: list = []
+        self._token_times: list = []     # perf_counter stamp per token
         self._output = None              # engine RequestOutput at terminal
         self._result: Optional[SubmitResult] = None
         self._error: Optional[Exception] = None
@@ -244,6 +255,7 @@ class InvocationHandle:
 
     # -- gateway-side ---------------------------------------------------
     def _on_token(self, req_id: int, token: int, index: int) -> None:
+        now = time.perf_counter()
         if index == 0:
             self._state = STREAMING
             if not self._ttft_observed:
@@ -251,14 +263,19 @@ class InvocationHandle:
                 # Eq. 1 TTFT feedback fires on token 0, not at batch
                 # drain: residency adapts while the request is decoding
                 self._gateway.runtime.observe_ttft(
-                    self.request.fn_name,
-                    time.perf_counter() - self.submit_s)
+                    self.request.fn_name, now - self.submit_s)
         if index < len(self._tokens):
             # crash-retry re-emission: the fresh engine replays the stream
             # from index 0; determinism makes the overwrite a no-op
             self._tokens[index] = int(token)
+            self._token_times[index] = now
         else:
             self._tokens.append(int(token))
+            self._token_times.append(now)
+
+    def _times(self, n: int) -> np.ndarray:
+        """Stamps of the first ``n`` tokens."""
+        return np.asarray(self._token_times[:n], np.float64)
 
     def _finalize(self, out) -> None:
         self._output = out
@@ -275,7 +292,7 @@ class InvocationHandle:
             fork_stats=self.fork_stats,
             reused_prefix_len=out.reused_prefix_len,
             status=out.status if out.status != "failed" else CANCELLED,
-            retries=self.retries)
+            retries=self.retries, token_times_s=self._times(len(out.tokens)))
         self._gateway._note_terminal(self)
 
     def _fail(self, error: Exception) -> None:
@@ -286,7 +303,8 @@ class InvocationHandle:
             req_id=self.req_id, fn_name=self.request.fn_name, kind=self.kind,
             tokens=np.asarray(self._tokens, np.int32),
             ttft_s=float("nan"), e2e_s=float("nan"),
-            fork_stats=self.fork_stats, status=FAILED, retries=self.retries)
+            fork_stats=self.fork_stats, status=FAILED, retries=self.retries,
+            token_times_s=self._times(len(self._tokens)))
         self._gateway._note_terminal(self)
 
 
@@ -363,6 +381,12 @@ class InvocationGateway:
         """
         now = (time.perf_counter() if request.arrival_s is None
                else request.arrival_s)
+        rid = new_req_id()
+        with jax.profiler.TraceAnnotation("tidal.submit", req_id=rid):
+            return self._submit(request, now, rid)
+
+    def _submit(self, request: InvocationRequest, now: float,
+                rid: int) -> InvocationHandle:
         rt = self.runtime
         with self._wake:
             rt._prune(now)
@@ -382,26 +406,27 @@ class InvocationGateway:
                 # is shed here, before forking an engine or spending any
                 # prefill — the shed decision honors the intended arrival,
                 # not the submit call
-                handle = InvocationHandle(self, request, -1, None, None,
+                handle = InvocationHandle(self, request, rid, None, None,
                                           "shed", None)
                 handle.submit_s = now
                 handle._state = SHED
                 self._note_terminal(handle)
                 return handle
             request, browned_out = self._admit_bounded(request)
-            key, engine, kind, stats = rt._engine_for(request.fn_name,
-                                                      request.event, now)
+            key, engine, kind, stats = rt._engine_for(
+                request.fn_name, request.event, now, req_id=rid)
             rt._count(request.fn_name, kind)
-            handle = InvocationHandle(self, request, -1, key, engine, kind,
+            handle = InvocationHandle(self, request, rid, key, engine, kind,
                                       stats)
             handle.submit_s = now        # TTFT includes the fork above
             handle.browned_out = browned_out
-            handle.req_id = engine.submit(
+            engine.submit(
                 prompt, request.max_new_tokens, submit_s=now,
                 temperature=request.temperature, top_p=request.top_p,
                 seed=request.seed, deadline_s=request.deadline_s,
                 priority=request.priority, token_cb=handle._on_token,
-                adapter_id=rt._adapter_id_for(request.fn_name, key))
+                adapter_id=rt._adapter_id_for(request.fn_name, key),
+                req_id=rid)
             self._live.append(handle)
             self._wake.notify_all()      # background pump: new work landed
             return handle
@@ -798,12 +823,7 @@ class InvocationGateway:
             if owner is not None and owner is not engine:
                 continue
             try:
-                if not self.interleave:
-                    engine.run()
-                elif quantum_tokens is not None:
-                    engine.step_tokens(quantum_tokens)
-                else:
-                    engine.step_n(quantum)
+                self._quantum(engine, quantum, quantum_tokens)
             except PoolExhausted:
                 # the engine dropped the one doomed request and recorded
                 # its 'failed' result — THAT handle raises the typed
@@ -825,6 +845,19 @@ class InvocationGateway:
             raise RuntimeError(
                 "gateway livelock: no engine could take a quantum "
                 f"({len(pending)} still pending)")
+
+    def _quantum(self, engine, quantum: int,
+                 quantum_tokens: Optional[int]) -> None:
+        """Run one engine's quantum in a ``tidal.quantum`` span of its steps."""
+        n0 = engine.n_steps
+        with jax.profiler.TraceAnnotation("tidal.quantum") as span:
+            if not self.interleave:
+                engine.run()
+            elif quantum_tokens is not None:
+                engine.step_tokens(quantum_tokens)
+            else:
+                engine.step_n(quantum)
+            span.set_metadata(steps=engine.n_steps - n0)
 
     # -- supervision ----------------------------------------------------
     def _service_retries(self) -> Optional[float]:
@@ -945,18 +978,19 @@ class InvocationGateway:
         now = time.perf_counter()
         try:
             prompt = np.asarray(req.prompt, np.int32).reshape(-1)
-            key, engine, kind, stats = rt._engine_for(req.fn_name,
-                                                      req.event, now)
+            key, engine, kind, stats = rt._engine_for(
+                req.fn_name, req.event, now, req_id=h.req_id)
             h.engine_key, h.engine, h.kind = key, engine, kind
             if stats is not None:
                 h.fork_stats = stats
             h._state = QUEUED
-            h.req_id = engine.submit(
+            engine.submit(
                 prompt, req.max_new_tokens, submit_s=h.submit_s,
                 temperature=req.temperature, top_p=req.top_p,
                 seed=req.seed, deadline_s=req.deadline_s,
                 priority=req.priority, token_cb=h._on_token,
-                adapter_id=rt._adapter_id_for(req.fn_name, key))
+                adapter_id=rt._adapter_id_for(req.fn_name, key),
+                req_id=h.req_id)
         except RuntimeFailure as e:
             h.engine = None
             h._fail(e)
@@ -994,7 +1028,8 @@ class InvocationGateway:
                     tokens=np.asarray(h._tokens, np.int32),
                     ttft_s=float("nan"), e2e_s=float("nan"),
                     fork_stats=h.fork_stats, status=CANCELLED,
-                    retries=h.retries)
+                    retries=h.retries,
+                    token_times_s=h._times(len(h._tokens)))
                 self._note_terminal(h)
             w = self.runtime._engines.get(h.engine_key)
             if w is not None and w.engine is engine:

@@ -653,7 +653,8 @@ class PagedKVCachePool:
         self.write_suffix(slot, sub_cache, 0, n_tokens, owner=owner)
 
     def write_suffix(self, slot: int, sub_cache: Any, start_token: int,
-                     n_tokens: int, owner: Optional[int] = None) -> None:
+                     n_tokens: int, owner: Optional[int] = None,
+                     req_id: int = -1) -> None:
         """Write positions ``start_token .. n_tokens-1`` into ``slot``.
 
         Maps any still-missing pages, then writes whole blocks from
@@ -662,6 +663,8 @@ class PagedKVCachePool:
         mid-page, never an aliased donor page (shared-page writes raise).
         Quantized mode re-quantizes the rewritten first block from its
         dequantized values, which is bit-exact (see ``repro.models.quant``).
+        The write is a ``tidal.arena.write`` profiler span carrying the
+        request's ``req_id`` and the pages written.
         """
         self._check_owner(slot, owner, "write KV into")
         self.ensure_len(slot, n_tokens, owner=owner)
@@ -675,7 +678,9 @@ class PagedKVCachePool:
             raise ValueError(
                 f"slot {slot}: refusing to write shared pages {shared} "
                 "(aliased prefix pages are copy-on-write)")
-        self._write_blocks(pages, sub_cache, first_block=first)
+        with jax.profiler.TraceAnnotation("tidal.arena.write", req_id=req_id,
+                                          pages=len(pages)):
+            self._write_blocks(pages, sub_cache, first_block=first)
 
     def _gather_pages(self, pages, length: int) -> Any:
         """Gather ``pages`` into a batch-1 dense fp cache of ``length``."""

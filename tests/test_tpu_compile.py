@@ -148,3 +148,38 @@ def test_flash_attention_unaligned_length_compiles(one_chip, monkeypatch):
         _spec(one_chip, (1, KV, S, d), jnp.bfloat16),
         _spec(one_chip, (1, KV, S, d), jnp.bfloat16)).compile()
     _assert_kernel(compiled)
+
+
+def test_paged_decode_carries_its_name_and_the_metric_signature(
+        one_chip, monkeypatch):
+    """The compiled kernel is named ``paged_decode_attention``, and the
+    benchmark's signature for it (``paged_decode_roofline.cold``) still
+    matches its HLO text as the device trace prints it: qwen3-14b-l10's
+    decode, 4 slots of 2560 tokens in pages of 8."""
+    import importlib.util
+    import pathlib
+    import re
+
+    from jax._src.lib import xla_client
+
+    bench = pathlib.Path(__file__).resolve().parents[1] / "bench"
+    monkeypatch.syspath_prepend(str(bench))        # the reader's benchlib
+    spec = importlib.util.spec_from_file_location(
+        "paged_roofline", bench / "metrics" / "paged_decode_roofline.cold.py")
+    metric = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(metric)
+    B, ps = 4, 8
+    args = _paged_specs(one_chip, B, 40, 8, 128, ps, 2560 // ps,
+                        jnp.bfloat16)
+    compiled = jax.jit(
+        lambda q, k, v, pt, ln: paged_decode_attention(
+            q, k, v, pt, ln, interpret=False)).lower(*args).compile()
+    opts = xla_client._xla.HloPrintOptions.short_parsable()
+    opts.print_operand_shape = True
+    text = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+    calls = [line.strip() for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 1
+    assert calls[0].startswith("paged_decode_attention")
+    assert re.search(metric.KERNEL.format(rows=B), calls[0])
+    assert not re.search(metric.KERNEL.format(rows=B + 1), calls[0])
