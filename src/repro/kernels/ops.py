@@ -5,8 +5,8 @@ The model layer calls these when ``cfg.attn_impl`` resolves to Pallas
 ``attn_impl='pallas'`` to run the kernels in interpret mode on the CPU);
 ``impl='xla'`` selects the pure-jnp reference instead.
 
-Wrappers own the layout glue (head-major transposes, block-size selection,
-shape-divisibility fallbacks) so kernels stay minimal.
+Wrappers own the layout glue (head-major transposes of the dense caches,
+block-size selection, shape-divisibility fallbacks) so kernels stay minimal.
 
 Under a ShardingPlan the attention entry points accept ``mesh=``: a
 Pallas call traced inside GSPMD-partitioned jit code would make XLA
@@ -98,7 +98,14 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
     """q: [B, H, d]; k_pages, v_pages: [P, ps, KV, d] (the page arena in the
     model's storage layout); page_table: [B, NB]; lengths: scalar or [B];
     k_scales, v_scales: optional [P, ps, KV] per-row scales for an int8
-    arena (dequantized inside the kernel).  Returns [B, H, d]."""
+    arena (dequantized inside the kernel).  Returns [B, H, d].
+
+    The Pallas kernel reads the arena as stored, with no transpose: its
+    grid is (B, blocks), a block ``ppb`` pages of every KV head, each page
+    ``[ps, KV, d]`` one DMA steered by the page table, and blocks past a
+    row's length issue no DMA.  ``ppb`` follows from the shapes: pages
+    for 256 keys within a fixed VMEM budget
+    (``paged_decode_attention._pages_per_block``)."""
     if (k_scales is None) != (v_scales is None):
         raise ValueError("pass both k_scales and v_scales, or neither")
     if impl == "xla":
@@ -134,14 +141,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
             out_specs=P(None, "model", None),
             check_vma=False)(q, k_pages, v_pages, k_scales, v_scales,
                              page_table, lengths)
-    # kernel wants the head-major arena [P, KV, ps, d] — same per-step
-    # transpose the dense decode path pays for its [B, T, KV, hd] cache
-    if k_scales is not None:
-        k_scales = k_scales.transpose(0, 2, 1)        # -> [P, KV, ps]
-        v_scales = v_scales.transpose(0, 2, 1)
-    return _paged_decode_pallas(q, k_pages.transpose(0, 2, 1, 3),
-                                v_pages.transpose(0, 2, 1, 3),
-                                page_table, lengths,
+    return _paged_decode_pallas(q, k_pages, v_pages, page_table, lengths,
                                 k_scales=k_scales, v_scales=v_scales)
 
 

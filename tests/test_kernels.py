@@ -237,9 +237,9 @@ def test_paged_decode_dequant_close_to_fp():
 
 
 def test_paged_decode_dequant_unit_scale_axis():
-    """The [P, KV, 1, ps] scale blocks (a unit axis so the TPU tiling takes
-    them) must steer each page's scales like its values: scales that differ
-    per page, row and head, at smollm-135m decode widths."""
+    """Each page's scales (copied beside its values, padded to whole tiles)
+    must steer like its values: scales that differ per page, row and head,
+    at smollm-135m decode widths."""
     from repro.models.quant import quantize_rows
 
     B, H, KV, ps, NB, d = 3, 9, 3, 16, 4, 64
@@ -257,6 +257,80 @@ def test_paged_decode_dequant_unit_scale_axis():
                                           k_scales=ks, v_scales=vs)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
+
+
+def _edge_rows(key, KV, d, ps, NB, lengths, int8):
+    """An arena of ``len(lengths)`` rows on shuffled pages plus one dummy
+    row (one key on the null page, page 0), as the engine's idle slots."""
+    from repro.models.quant import quantize_rows
+
+    B = len(lengths) + 1
+    kp, vp, pt = _page_arena(key, B, KV, d, ps, NB, B * NB + 1)
+    pt = pt.at[-1].set(0)
+    lengths = jnp.asarray(list(lengths) + [1], jnp.int32)
+    if not int8:
+        return kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16), pt, \
+            lengths, {}
+    kq, ks = quantize_rows(kp)
+    vq, vs = quantize_rows(vp)
+    return kq, vq, pt, lengths, dict(k_scales=ks, v_scales=vs)
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("KV,G,d", [
+    (2, 5, 128),                # qwen3-14b's head group, two KV heads
+    (4, 1, 128),                # G 1: one query per KV head (MHA)
+    (1, 8, 64),                 # G 8, MQA, d padded to 128 lanes
+])
+def test_paged_decode_block_edges(KV, G, d, int8):
+    """Pages of 8 give blocks of 32 pages and 40 logical pages two blocks,
+    the second short.  Rows end at 1 key, one page, one block, one block
+    and a key, and the whole table; a dummy row sits on the null page."""
+    from repro.kernels.paged_decode_attention import _pages_per_block
+
+    ps, NB = 8, 40
+    ppb = _pages_per_block(NB, ps, KV, 128, 1 if int8 else 2)
+    assert ppb == 32 and NB % ppb
+    kp, vp, pt, lengths, scales = _edge_rows(
+        jax.random.fold_in(RNG, 59), KV, d, ps, NB,
+        [1, ps, ppb * ps, ppb * ps + 1, NB * ps], int8)
+    q = jax.random.normal(jax.random.fold_in(RNG, 61),
+                          (len(lengths), KV * G, d), jnp.bfloat16)
+    out = ops.paged_decode_attention(q, kp, vp, pt, lengths, **scales)
+    want = ref.paged_decode_attention_ref(q, kp, vp, pt, lengths, **scales)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32),
+                               **_tol(jnp.bfloat16))
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_paged_decode_reads_the_arena_in_place(int8):
+    """The wrapper hands the kernel the arena as stored: no transpose of an
+    arena-shaped operand anywhere in the traced program (the kernel's body
+    included), at qwen3-14b's aligned page [8, 8, 128]."""
+    kp, vp, pt, lengths, scales = _edge_rows(RNG, 8, 128, 8, 4, [17],
+                                             int8)
+    q = jnp.zeros((2, 40, 128), jnp.bfloat16)
+    arenas = {kp.shape} | {s.shape for s in scales.values()}
+
+    def transposed(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "transpose" and any(
+                    getattr(v.aval, "shape", None) in arenas
+                    for v in eqn.invars):
+                yield eqn
+            for param in eqn.params.values():
+                for sub in param if isinstance(param, (list, tuple)) \
+                        else [param]:
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from transposed(sub)
+
+    jaxpr = jax.make_jaxpr(
+        lambda *a: ops.paged_decode_attention(*a, **scales))(
+            q, kp, vp, pt, lengths).jaxpr
+    assert "paged_decode_attention" in str(jaxpr)
+    assert not list(transposed(jaxpr))
 
 
 def test_paged_decode_scales_require_pair():

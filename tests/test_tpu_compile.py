@@ -57,12 +57,29 @@ def _assert_kernel(compiled):
 
 
 def _paged_specs(sharding, B, H, KV, d, ps, NB, kv_dtype):
+    """q, the K and V arenas in the model's storage layout
+    [n_pages, ps, KV, d], the page table and the lengths."""
     n_pages = 1 + B * NB
     return [_spec(sharding, (B, H, d), jnp.bfloat16),
-            _spec(sharding, (n_pages, KV, ps, d), kv_dtype),
-            _spec(sharding, (n_pages, KV, ps, d), kv_dtype),
+            _spec(sharding, (n_pages, ps, KV, d), kv_dtype),
+            _spec(sharding, (n_pages, ps, KV, d), kv_dtype),
             _spec(sharding, (B, NB), jnp.int32),
             _spec(sharding, (B,), jnp.int32)]
+
+
+def _compile_paged(sharding, B, H, KV, d, ps, NB, kv_dtype):
+    """Compile the paged kernel for one chip; int8 arenas bring their
+    [n_pages, ps, KV] float32 scales."""
+    args = _paged_specs(sharding, B, H, KV, d, ps, NB, kv_dtype)
+    if kv_dtype != jnp.int8:
+        return jax.jit(
+            lambda q, k, v, pt, ln: paged_decode_attention(
+                q, k, v, pt, ln, interpret=False)).lower(*args).compile()
+    scales = _spec(sharding, (1 + B * NB, ps, KV), jnp.float32)
+    return jax.jit(
+        lambda q, k, v, pt, ln, ks, vs: paged_decode_attention(
+            q, k, v, pt, ln, k_scales=ks, v_scales=vs, interpret=False)
+    ).lower(*args, scales, scales).compile()
 
 
 @pytest.mark.parametrize("ps", [8, SERVE_PAGE_SIZE])
@@ -79,15 +96,24 @@ def test_paged_decode_bf16_compiles(one_chip, ps):
 @pytest.mark.parametrize("ps", [8, SERVE_PAGE_SIZE])
 def test_paged_decode_int8_compiles(one_chip, ps):
     s = SMOLLM
-    NB = s["max_len"] // ps
-    args = _paged_specs(one_chip, s["B"], s["H"], s["KV"], s["d"], ps, NB,
-                        jnp.int8)
-    scales = _spec(one_chip, (1 + s["B"] * NB, s["KV"], ps), jnp.float32)
-    compiled = jax.jit(
-        lambda q, k, v, pt, ln, ks, vs: paged_decode_attention(
-            q, k, v, pt, ln, k_scales=ks, v_scales=vs, interpret=False)
-    ).lower(*args, scales, scales).compile()
+    compiled = _compile_paged(one_chip, s["B"], s["H"], s["KV"], s["d"], ps,
+                              s["max_len"] // ps, jnp.int8)
     _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+def test_paged_decode_qwen3_cell_compiles(one_chip, kv_dtype):
+    """qwen3-14b-l10's decode as the benchmark serves it: 4 slots of 2560
+    tokens in pages of 8 (1281 pages), 40 heads over 8 KV heads of 128."""
+    _assert_kernel(_compile_paged(one_chip, 4, 40, 8, 128, 8, 2560 // 8,
+                                  kv_dtype))
+
+
+def test_paged_decode_mqa_head_dim_256_compiles(one_chip):
+    """One KV head of width 256 (MQA with wide heads), pages of 8."""
+    _assert_kernel(_compile_paged(one_chip, 4, 8, 1, 256, 8, 2048 // 8,
+                                  jnp.bfloat16))
 
 
 def test_dense_decode_compiles(one_chip):
