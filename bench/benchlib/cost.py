@@ -38,11 +38,10 @@ def roofline_share(work: dict, seconds: float, peak: dict) -> tuple:
 
 
 def decode_token_flops(c: dict, positions) -> float:
-    """Model FLOPs of decoding tokens at ``positions``: two per weight of
-    every layer and of the output head, plus attention over the keys."""
-    d = weights.dims(c)
-    n = weights.n_params(c)
-    dense = 2 * n["per_layer"] * d["L"] + 2 * d["V"] * d["D"]
+    """Model FLOPs of decoding tokens at ``positions``: two per active
+    weight (every layer's share that a token reaches, and the output
+    head), plus attention over the keys."""
+    fam = weights.family(c)
     keys = np.asarray(positions, np.int64) + 1
-    attn = 4 * d["L"] * d["H"] * d["hd"] * int(keys.sum())
-    return float(dense * len(keys) + attn)
+    return float(2 * fam.n_params(c)["active"] * len(keys)
+                 + fam.attention_flops_per_key(c) * int(keys.sum()))

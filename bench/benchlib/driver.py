@@ -4,9 +4,9 @@ measure one window, and keep a record of every request.
 The program gets only generated inputs: weights from ``weights``, prompts
 and arrivals from ``traffic``.  The open loop copies
 ``InvocationGateway.replay`` (due times, pumping between arrivals, arrivals
-backdated to their due time) and clocks each ``submit`` call itself; the
-closed loop is one caller that sends its next request when the last one
-finished.  ``jax.profiler.TraceAnnotation`` spans named ``bench.*`` mark the
+backdated to their due time) and clocks each ``submit`` call itself; in
+the closed loop each of the mix's callers sends its next request when its
+last one finished.  ``jax.profiler.TraceAnnotation`` spans named ``bench.*`` mark the
 benchmark's own calls into the runtime.
 """
 
@@ -30,14 +30,7 @@ TRACE_DIR = ".bench_out/traces"
 def model_config(c: dict):
     """The program's ModelConfig for a configuration file."""
     from repro.models.config import ModelConfig
-    d = weights.dims(c)
-    return ModelConfig(
-        name=c["name"], family=c["family"], n_layers=d["L"],
-        d_model=d["D"], n_heads=d["H"], n_kv_heads=d["KV"],
-        head_dim=d["hd"], d_ff=d["F"], vocab_size=d["V"],
-        qk_norm=d["qk_norm"], tied_embeddings=d["tied"],
-        rope_theta=float(c["rope_theta"]), norm_eps=float(c["rms_norm_eps"]),
-        dtype=c["dtype"])
+    return ModelConfig(**weights.family(c).model_config(c))
 
 
 class CacheCounter:
@@ -112,7 +105,7 @@ class Runtime:
             # the runtime's own template server, with the cell's budget
             self.rt.server.device_budget = int(
                 cell["template_device_budget_bytes"])
-        vocab = weights.dims(cfg)["V"]
+        vocab = cfg["vocab_size"]
         self.templates = traffic.templates(mix, cell["functions"], vocab,
                                            seed)
         for f in range(cell["functions"]):
@@ -153,7 +146,7 @@ class Runtime:
         if stays_warm:
             first = [(f, lens[0]) for f in range(n_fn)]
             plan = first + plan + first
-        vocab = weights.dims(self.cfg)["V"]
+        vocab = self.cfg["vocab_size"]
         rng = traffic.rng_for(self.seed, 9)
         gw = self.rt.gateway
         for i, (f, n) in enumerate(plan):
@@ -175,7 +168,8 @@ def _record(r: traffic.Request, due: float, submit_at: float, h) -> dict:
            "max_new": r.max_new, "due": due, "submit_at": submit_at,
            "status": "unfinished", "ttft_s": float("nan"),
            "e2e_s": float("nan"), "n_out": 0, "tokens": np.zeros(0, np.int32),
-           "reused": 0, "kind": "", "reused_bytes": 0, "streamed_bytes": 0}
+           "reused": 0, "kind": "", "reused_bytes": 0, "streamed_bytes": 0,
+           "token_times_s": None}
     if not h.done:
         return rec
     try:
@@ -186,7 +180,8 @@ def _record(r: traffic.Request, due: float, submit_at: float, h) -> dict:
     rec.update(status=res.status, ttft_s=float(res.ttft_s),
                e2e_s=float(res.e2e_s), n_out=len(res.tokens),
                tokens=np.asarray(res.tokens, np.int32),
-               reused=int(res.reused_prefix_len), kind=res.kind)
+               reused=int(res.reused_prefix_len), kind=res.kind,
+               token_times_s=getattr(res, "token_times_s", None))
     if res.fork_stats is not None:
         rec["reused_bytes"] = int(res.fork_stats.reused_bytes)
         rec["streamed_bytes"] = int(res.fork_stats.streamed_bytes)
@@ -236,34 +231,63 @@ def open_loop(run: Runtime, schedule: list, seconds: float,
 
 def closed_loop(run: Runtime, sequence: list, seconds: float,
                 tslice: Slice | None) -> tuple:
-    """One caller: send, wait for the answer, send the next, until the
-    window closes; the request in flight then is waited for."""
+    """``callers`` (the mix's, default 1) concurrent callers: each sends
+    its next request when its last one finished, taking requests in order
+    from the one ``sequence``, until the window closes; the requests in
+    flight then are waited for."""
     gw = run.rt.gateway
-    records = []
+    callers = int(run.mix.get("callers", 1))
+    todo = iter(sequence)
+    live, records = [], []      # (index, request, handle) in flight
     t0 = time.perf_counter()
-    for r in sequence:
+    deadline = t0 + seconds + DRAIN_S
+
+    def finished() -> bool:
+        return any(h.done for _, _, h in live)
+
+    while True:
         t = time.perf_counter() - t0
-        if t >= seconds:
+        while len(live) < callers and t < seconds:
+            r = next(todo, None)
+            if r is None:
+                break
+            if tslice is not None:
+                tslice.poll(t)
+            with jax.profiler.TraceAnnotation("bench.submit"):
+                live.append((len(records), r,
+                             gw.submit(run.request(r, None))))
+            records.append(None)
+        if not live:
             break
-        if tslice is not None:
-            tslice.poll(t)
-        with jax.profiler.TraceAnnotation("bench.submit"):
-            h = gw.submit(run.request(r, None))
-        due = h.submit_s - t0
-        deadline = time.perf_counter() + seconds + DRAIN_S - t
-        while not h.done and time.perf_counter() < deadline:
+        while not finished() and time.perf_counter() < deadline:
             step = 0.05 if tslice is None else max(
                 1e-3, tslice.next_event() - (time.perf_counter() - t0))
             with jax.profiler.TraceAnnotation("bench.wait"):
-                gw.pump(wait_for=h, timeout=min(step, 1.0))
+                gw.pump(until=finished, timeout=min(step, 1.0))
             if tslice is not None:
                 tslice.poll(time.perf_counter() - t0)
-        records.append(_record(r, due, due, h))
-        gw.pump()
-        del h
+        live = _collect(live, records, t0,
+                        late=time.perf_counter() >= deadline)
+        # the gateway keeps finished handles, and through them their
+        # engines' weights, until a pump drops them: drop them before the
+        # next request forks
+        gw.pump(timeout=0)
     if tslice is not None:
         tslice.stop(time.perf_counter() - t0)
     return records, t0
+
+
+def _collect(live: list, records: list, t0: float, late: bool) -> list:
+    """Record the finished requests in flight (all of them when ``late``)
+    and return the rest; no finished handle outlives this call."""
+    still = []
+    for i, r, h in live:
+        if h.done or late:
+            due = h.submit_s - t0
+            records[i] = _record(r, due, due, h)
+        else:
+            still.append((i, r, h))
+    return still
 
 
 def memory_peak_bytes(devices) -> int:

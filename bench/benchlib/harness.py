@@ -11,7 +11,8 @@ import time
 
 import jax
 
-from benchlib import check, driver, peaks, spec, traffic, trace, weights
+from benchlib import (check, driver, peaks, program, spec, traffic, trace,
+                      weights)
 
 
 def log(*a) -> None:
@@ -31,6 +32,7 @@ class Ctx:
     trace: object = None     # trace.Trace of the traced slice, or None
     trace_ns: tuple = None   # the slice on the trace's clock
     slice_s: tuple = None    # the slice on the window's clock
+    counters: dict = None    # the program's compile counter over the window
 
 
 def host_memory() -> str:
@@ -45,19 +47,27 @@ def host_memory() -> str:
                      if k in rows)
 
 
+def device_memory(devices) -> str:
+    """Bytes in use, the peak, and the largest free block on each chip."""
+    keys = ("bytes_in_use", "peak_bytes_in_use", "largest_free_block_bytes",
+            "bytes_limit")
+    return "; ".join(", ".join(f"{k} {d.memory_stats().get(k)}" for k in keys)
+                     for d in devices)
+
+
 def rehearsal(cfg: dict, cell: dict, mix: dict) -> tuple:
     """Tiny stand-ins of a cell's files for a run on the CPU: every path of
-    the harness, nothing of the chip's sizes."""
-    cfg = dict(cfg, hidden_size=64, num_attention_heads=4,
-               num_key_value_heads=2, head_dim=16, intermediate_size=128,
-               vocab_size=512, num_hidden_layers=2)
+    the harness, nothing of the chip's sizes.  The configuration's family
+    gives its own tiny sizes; the callers are capped at the slots."""
+    cfg = weights.family(cfg).rehearsal(cfg)
     # the gap limit at these sizes, set from CPU readings over 19 seeds
     # of each cell: sound runs 0-0.0327, the float8 control 0.0977-0.760
     cell = dict(cell, functions=min(cell["functions"], 2), n_slots=4,
                 max_len=128, template_device_budget_bytes=None,
                 rate_per_s=2.0, check=dict(cell["check"], max_logit_gap=0.06))
     mix = dict(mix, shared_prefix_tokens=min(
-        mix.get("shared_prefix_tokens", 0), 16))
+        mix.get("shared_prefix_tokens", 0), 16),
+        callers=min(int(mix.get("callers", 1)), cell["n_slots"]))
     mix["prompt_tokens"] = {"dist": "lognormal", "median": 16, "sigma": 0.5,
                             "min": 8, "max": 32, "levels": 2}
     out = mix["output_tokens"]
@@ -101,7 +111,7 @@ def run(name: str, seed: int, seconds: float, traced: bool, t_start: float,
     log(f"cache: {cache or 'off (CPU backend)'}")
     counter = driver.CacheCounter()
 
-    vocab = weights.dims(cfg)["V"]
+    vocab = cfg["vocab_size"]
     if mix["loop"] == "open":
         reqs = traffic.open_loop(mix, cell["functions"], vocab,
                                  cell["rate_per_s"], seconds, seed)
@@ -109,7 +119,10 @@ def run(name: str, seed: int, seconds: float, traced: bool, t_start: float,
         reqs = traffic.closed_loop(mix, cell["functions"], vocab, seed)
     run_ = driver.Runtime(cfg, cell, mix, seed, log=log)
     run_.warm_up(traffic.unshared_lengths(mix, reqs), log=log)
+    if not rehearse:
+        log(f"device memory after warm-up: {device_memory(devices)}")
     hits0, misses0 = counter.hits, counter.misses
+    compiles0 = run_.rt.stats().get("compile")
 
     tslice = None
     if traced:
@@ -118,8 +131,20 @@ def run(name: str, seed: int, seconds: float, traced: bool, t_start: float,
                               min(tr["seconds"], seconds / 2),
                               keep_trace or driver.trace_dir(name, seed))
     loop = driver.open_loop if mix["loop"] == "open" else driver.closed_loop
-    records, t0 = loop(run_, reqs, seconds, tslice)
+    try:
+        records, t0 = loop(run_, reqs, seconds, tslice)
+    except Exception:
+        # what the window had compiled and held when it stopped
+        log(f"window stopped: compile cache {counter.hits - hits0} hits, "
+            f"{counter.misses - misses0} misses; program compiles "
+            f"{program.compile_delta(compiles0, run_.rt.stats().get('compile'))}")
+        if not rehearse:
+            log(f"device memory when the window stopped: "
+                f"{device_memory(devices)}")
+        raise
     setup_s = t0 - t_start
+    compiles = program.compile_delta(compiles0,
+                                     run_.rt.stats().get("compile"))
     log(f"window: {len(records)} requests; compile cache in the window: "
         f"{counter.hits - hits0} hits, {counter.misses - misses0} misses")
     log(f"host memory: {host_memory()}")
@@ -135,7 +160,8 @@ def run(name: str, seed: int, seconds: float, traced: bool, t_start: float,
     del run_, rt
     gc.collect()
 
-    ctx = Ctx(records, float(seconds), setup_s, cfg, cell, peak, runtime)
+    ctx = Ctx(records, float(seconds), setup_s, cfg, cell, peak, runtime,
+              counters=compiles)
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind, "count": len(devices),
               "memory_peak_bytes": mem_peak}

@@ -33,7 +33,12 @@ class Span:
 def load(path: str) -> list:
     """Every ``tidal.*`` host span of an ``.xplane.pb``, in start order."""
     from jax.profiler import ProfileData
-    pd = ProfileData.from_file(path)
+    return spans(ProfileData.from_file(path))
+
+
+def spans(pd) -> list:
+    """Every ``tidal.*`` host span of a loaded ``ProfileData``, in start
+    order."""
     out = []
     for i, plane in enumerate(pd.planes):
         if not plane.name.startswith("/host:"):

@@ -4,7 +4,7 @@ from __future__ import annotations
 
 import bisect
 
-from benchlib import stats, trace, weights
+from benchlib import stats, trace
 
 
 def ttft_ms(ctx, q: float):
@@ -49,7 +49,7 @@ def decode_calls(ctx, signature: str):
     ev = kernel_events(ctx, signature)
     if not ev:
         return None, ev
-    return len(ev) / weights.dims(ctx.cfg)["L"], ev
+    return len(ev) / ctx.cfg["num_hidden_layers"], ev
 
 
 def step_programs(ctx, signature: str) -> list:

@@ -1,5 +1,6 @@
 """Find a cell's files by name: BENCHMARK.json, the cell, its configuration,
-its traffic mix, its reference and its metric readers."""
+its model family's plug-in, its traffic mix, its reference and its metric
+readers."""
 
 from __future__ import annotations
 
@@ -46,6 +47,19 @@ def _load_module(path: pathlib.Path, modname: str) -> types.ModuleType:
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def family(name: str) -> types.ModuleType:
+    """A model family's plug-in (families/<name>.py), loaded once per
+    file: the layout, sizes and ``ModelConfig`` of that family."""
+    path = BENCH_DIR / "families" / f"{name}.py"
+    if path not in _FAMILIES:
+        _FAMILIES[path] = _load_module(
+            path, "bench_family_" + name.replace(".", "_").replace("-", "_"))
+    return _FAMILIES[path]
+
+
+_FAMILIES: dict = {}
 
 
 def reference(path: str) -> types.ModuleType:

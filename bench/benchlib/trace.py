@@ -4,8 +4,9 @@ gaps labelled by what the host was doing.
 Device operations are the events of each ``/device:TPU:<n>`` plane's
 "XLA Ops" line; busy time is the union of their intervals.  Host spans are
 the ``jax.profiler.TraceAnnotation`` events the benchmark writes around its
-own calls into the runtime (names starting ``bench.``).  All times are the
-profiler's nanoseconds.
+own calls into the runtime (names starting ``bench.``) and, apart, the
+program's own ``tidal.*`` spans (``benchlib.program``), which name idle gaps
+where they cover them.  All times are the profiler's nanoseconds.
 """
 
 from __future__ import annotations
@@ -27,6 +28,7 @@ class Trace:
     ops: list        # per device: [(name, start, end)]
     modules: list    # per device: [(name, start, end)]
     host: list       # [(name, start, end)] benchmark spans
+    program: list = dataclasses.field(default_factory=list)  # program.Span
 
 
 def find_xplane(log_dir: str) -> str:
@@ -39,6 +41,8 @@ def find_xplane(log_dir: str) -> str:
 
 def load(path: str) -> Trace:
     from jax.profiler import ProfileData
+
+    from benchlib import program
     pd = ProfileData.from_file(path)
     ops, modules, host = [], [], []
     for plane in pd.planes:
@@ -58,7 +62,7 @@ def load(path: str) -> Trace:
                 host.extend((e.name, e.start_ns, e.start_ns + e.duration_ns)
                             for e in line.events
                             if e.name.startswith(BENCH_PREFIX))
-    return Trace(ops, modules, host)
+    return Trace(ops, modules, host, program.spans(pd))
 
 
 def span(trace: Trace, name: str) -> tuple:
@@ -158,7 +162,10 @@ def short_module(name: str) -> str:
 
 def reduce(trace: Trace, lo: float, hi: float, top: int = 10) -> dict:
     """Busy seconds (mean over devices), window seconds, the device ops
-    that took most time and the longest idle gaps, all inside ``[lo, hi]``."""
+    that took most time and the longest idle gaps, all inside ``[lo, hi]``;
+    a gap is named by the innermost program span over it, else by the
+    benchmark's (``program.label_gap``)."""
+    from benchlib import program
     n_dev = max(1, len(trace.ops))
     busy = sum(busy_ns(o, lo, hi) for o in trace.ops) / n_dev * 1e-9
     ops = op_seconds(trace.ops[0] if trace.ops else [], lo, hi)
@@ -170,6 +177,6 @@ def reduce(trace: Trace, lo: float, hi: float, top: int = 10) -> dict:
         "busy_s": busy,
         "window_s": (hi - lo) * 1e-9,
         "device_ops": [[f"{k} ({op_kind(ex)})", t] for k, (t, ex) in top_ops],
-        "idle_gaps": [[label_gap(trace, mods, s, e), (e - s) * 1e-9]
-                      for s, e in g],
+        "idle_gaps": [[program.label_gap(trace, trace.program, mods, s, e),
+                       (e - s) * 1e-9] for s, e in g],
     }

@@ -134,9 +134,12 @@ def open_loop(mix: dict, n_functions: int, vocab: int, rate: float,
 
 
 def closed_loop(mix: dict, n_functions: int, vocab: int, seed: int,
-                n: int = 256) -> list:
-    """The sequence one closed-loop caller sends: functions in turn, each
-    length level once per cycle in a seeded order."""
+                n: int | None = None) -> list:
+    """The one sequence the closed loop's callers take their requests from,
+    in order: functions in turn, each length level once per cycle in a
+    seeded order; 256 requests per caller unless ``n`` says."""
+    if n is None:
+        n = 256 * int(mix.get("callers", 1))
     order = rng_for(seed, 2)
     pl = levels(mix["prompt_tokens"])
     ol = levels(mix["output_tokens"])

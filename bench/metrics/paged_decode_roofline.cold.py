@@ -10,8 +10,7 @@ from benchlib import cost, readers, stats
 # The paged decode-attention kernel in a TPU trace, by its signature: a
 # custom call that computes one query row per slot and takes the engine's
 # page table (int32, one row per slot) as its first operand.  No prefill
-# kernel has that signature.  The Pallas call carries no name of its own
-# yet (it shows as "closed_call"), so its name cannot select it.
+# kernel has that signature.
 KERNEL = r"^\S+ = \w+\[{rows},[^\]]*\]\S* custom-call\(s32\[{rows},\d+\]"
 
 

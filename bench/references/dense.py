@@ -9,6 +9,10 @@ imports nothing of the program under test.
 
 ``mode="fp8"`` is the control: every matrix product takes float8 (e4m3)
 operands, the weights scaled per tensor and the activations per row.
+
+Another family's reference may build on this one: ``attention``,
+``swiglu`` and ``sizes`` are its parts, and ``logits_at`` runs any layer
+function over the layers the weights module draws.
 """
 
 from __future__ import annotations
@@ -52,9 +56,10 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-@functools.partial(jax.jit, static_argnames=("d", "mode"))
-def _layer(x, p, *, d, mode):
-    """One decoder layer over ``x`` [N, T, D] (causal within each row)."""
+def attention(x, p, d, mode):
+    """``x`` plus its causal grouped-query attention sublayer, ``x`` [N, T,
+    D] (causal within each row); leaves under ``attn.`` and
+    ``attn_norm``."""
     H, KV, hd, eps, theta = d["H"], d["KV"], d["hd"], d["eps"], d["theta"]
     N, T, _ = x.shape
     pos = jnp.arange(T)
@@ -81,11 +86,23 @@ def _layer(x, p, *, d, mode):
         return o.reshape(T, H * hd)
 
     o = jax.lax.map(attend, (q, k, v))
-    x = x + _mm(o, p["attn.wo"], mode)
-    h = _rmsnorm(x, p["mlp_norm"], eps)
-    g = _mm(h, p["mlp.w_gate"], mode)
-    u = _mm(h, p["mlp.w_up"], mode)
-    return x + _mm(jax.nn.silu(g) * u, p["mlp.w_down"], mode)
+    return x + _mm(o, p["attn.wo"], mode)
+
+
+def swiglu(h, w_gate, w_up, w_down, mode):
+    """The SwiGLU feed-forward of ``h``."""
+    g = _mm(h, w_gate, mode)
+    u = _mm(h, w_up, mode)
+    return _mm(jax.nn.silu(g) * u, w_down, mode)
+
+
+@functools.partial(jax.jit, static_argnames=("d", "mode"))
+def _layer(x, p, *, d, mode):
+    """One decoder layer over ``x`` [N, T, D] (causal within each row)."""
+    x = attention(x, p, d, mode)
+    h = _rmsnorm(x, p["mlp_norm"], d["eps"])
+    return x + swiglu(h, p["mlp.w_gate"], p["mlp.w_up"], p["mlp.w_down"],
+                      mode)
 
 
 @functools.partial(jax.jit, static_argnames=("tied", "eps", "mode"))
@@ -95,28 +112,38 @@ def _head(h, top, *, tied, eps, mode):
     return _mm(h, w, mode)
 
 
+def sizes(c: dict, **extra) -> dict:
+    """The static sizes a layer function reads, hashable, with ``extra``."""
+    dd = weights.dims(c)
+    return _Frozen({"H": dd["H"], "KV": dd["KV"], "hd": dd["hd"],
+                    "eps": float(c["rms_norm_eps"]),
+                    "theta": float(c["rope_theta"]),
+                    "qk_norm": dd["qk_norm"], **extra})
+
+
 def logits_at(c: dict, seed: int, fn: int, seqs: list, positions: list,
-              mode: str = "f32", pad_to: int = 512) -> list:
+              mode: str = "f32", pad_to: int = 512, layer=None,
+              d: dict | None = None) -> list:
     """Logits of function ``fn`` at ``positions[i]`` of sequence ``seqs[i]``.
 
     One sequence at a time, padded to a multiple of ``pad_to`` (causal
     attention keeps the padding out of every real position), so the few
     padded lengths compile once.  Each layer's weights are drawn once for
-    all sequences.  Returns one float32 ``[len(positions[i]), vocab]``
-    device array per sequence."""
-    dd = weights.dims(c)
-    eps = float(c["rms_norm_eps"])
-    d = _Frozen({"H": dd["H"], "KV": dd["KV"], "hd": dd["hd"], "eps": eps,
-                 "theta": float(c["rope_theta"]), "qk_norm": dd["qk_norm"]})
+    all sequences and run through ``layer(x, p, d=d, mode=mode)`` (this
+    family's ``_layer`` and ``sizes(c)`` unless given: another family's
+    reference passes its own).  Returns one float32 ``[len(positions[i]),
+    vocab]`` device array per sequence."""
+    layer = layer or _layer
+    d = d or sizes(c)
     top = weights.top_params(c, seed, fn)
     xs = []
     for s in seqs:
         toks = np.zeros((1, pad_to * -(-len(s) // pad_to)), np.int32)
         toks[0, :len(s)] = s
         xs.append(jnp.take(top["embed"], jnp.asarray(toks), axis=0))
-    for layer in range(dd["L"]):
-        p = weights.layer_params(c, seed, fn, layer)
-        xs = [_layer(x, p, d=d, mode=mode) for x in xs]
+    for i in range(c["num_hidden_layers"]):
+        p = weights.layer_params(c, seed, fn, i)
+        xs = [layer(x, p, d=d, mode=mode) for x in xs]
         del p
     out = []
     for x, pp in zip(xs, positions):
@@ -124,7 +151,8 @@ def logits_at(c: dict, seed: int, fn: int, seqs: list, positions: list,
         rows = np.zeros(pad_to * -(-len(pp) // pad_to), np.int32)
         rows[:len(pp)] = pp
         h = x[0, jnp.asarray(rows)]
-        z = _head(h, top, tied=dd["tied"], eps=eps, mode=mode)
+        z = _head(h, top, tied=bool(c["tie_word_embeddings"]),
+                  eps=d["eps"], mode=mode)
         out.append(z[:len(pp)])
     return out
 

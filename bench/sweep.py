@@ -35,7 +35,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     import jax
-    from benchlib import driver, readers, spec, stats, traffic, weights
+    from benchlib import driver, readers, spec, stats, traffic
     from benchlib.harness import Ctx, log
     from repro.utils import enable_compile_cache
 
@@ -47,7 +47,7 @@ def main(argv=None) -> int:
                       spec.mix(w["traffic"]))
     enable_compile_cache()
     run = driver.Runtime(cfg, cell, mix, args.seed, log=log)
-    vocab = weights.dims(cfg)["V"]
+    vocab = cfg["vocab_size"]
     rates = [float(r) for r in args.rates.split(",")]
     scheds = [traffic.open_loop(mix, cell["functions"], vocab, rate,
                                 args.seconds, args.seed + i)

@@ -51,3 +51,22 @@ def test_result_line_names_the_cells_end_to_end_metrics(run):
     assert set(out["metrics"]) == want
     assert list(out)[-1] == "check"
     assert out["device"]["count"] == 1
+
+
+def test_a_window_that_stops_logs_its_compiles_and_raises(monkeypatch,
+                                                          capsys):
+    """A window that raises, as a serve program that cannot reserve its
+    memory does, is not swallowed: the run logs what the window had
+    compiled and raises on, and prints no result."""
+    from benchlib import driver
+
+    def stops(run, reqs, seconds, tslice):
+        raise ValueError("RESOURCE_EXHAUSTED: a stand-in")
+
+    monkeypatch.setattr(driver, "closed_loop", stops)
+    monkeypatch.setattr(driver, "open_loop", stops)
+    with pytest.raises(ValueError, match="RESOURCE_EXHAUSTED"):
+        harness.run(CELLS[0], SEED, 2.0, False, time.perf_counter(),
+                    rehearse=True)
+    err = capsys.readouterr().err
+    assert "window stopped: compile cache 0 hits, 0 misses" in err

@@ -158,7 +158,7 @@ def test_gap_labels_prefer_the_innermost_program_span():
 def test_recorded_trace_has_no_program_spans_and_reads_as_before():
     t = trace.load(str(DATA))
     assert program.load(str(DATA)) == []
-    assert not hasattr(t, "program")
+    assert t.program == []               # loaded with the trace, none here
     lo = min(s for _, s, _ in t.ops[0])
     hi = max(e for _, _, e in t.ops[0])
     red = trace.reduce(t, lo, hi)

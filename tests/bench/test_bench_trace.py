@@ -64,7 +64,8 @@ def test_recorded_trace_kernel_time_and_roofline():
     cfg = {"hidden_size": 576, "num_attention_heads": 9,
            "num_key_value_heads": 3, "head_dim": 64,
            "intermediate_size": 1536, "vocab_size": 49152,
-           "num_hidden_layers": 1, "tie_word_embeddings": True}
+           "num_hidden_layers": 1, "tie_word_embeddings": True,
+           "family": "dense"}
     import numpy as np
     lengths = np.linspace(1, 2000, 32).astype(np.int32)
     work = cost.paged_decode(cfg, list(np.tile(lengths - 1, 5)), 5, 32, 8)
@@ -96,7 +97,7 @@ def test_decode_steps_are_the_programs_that_run_the_kernel():
     assert [(s, e) for _, s, e in steps] == [(8, 50), (100, 130)]
     # two decode tokens (positions 100 and 101) over 72 ns of steps
     flops = cost.decode_token_flops(cfg, [100, 101])
-    assert flops > 2 * 2 * weights.n_params(cfg)["per_layer"] * 10
+    assert flops > 2 * 2 * weights.n_params(cfg)["active"]
     want = 100 * flops / 72e-9 / peaks.peaks_for("TPU v5 lite")["bf16_flops"]
     assert spec.metric_reader("decode_mfu.cold").read(ctx) == pytest.approx(
         want)

@@ -4,6 +4,9 @@ rates within the mix's parameters."""
 import collections
 import json
 import pathlib
+import time
+import types
+import weakref
 
 import numpy as np
 import pytest
@@ -12,10 +15,17 @@ import _benchpath  # noqa: F401
 from benchlib import traffic
 
 MIXES = pathlib.Path(_benchpath.BENCH) / "mixes"
+# concurrent callers: the toy family's mix (no cell of the benchmark has
+# more than one caller yet)
+CONCURRENT = pathlib.Path(__file__).parent / "data" / "toy_moe" / "mix.json"
 
 
 def _mix(name):
     return json.loads((MIXES / f"{name}.json").read_text())
+
+
+def _concurrent():
+    return json.loads(CONCURRENT.read_text())
 
 
 def _open(seed, rate=2.0, seconds=60.0):
@@ -95,3 +105,81 @@ def test_closed_loop_alternates_functions_over_fixed_lengths():
     assert all(128 <= n <= 2048 for n in lv)
     assert (min(lv), max(lv)) == (128, 2048)    # both clip ends are sent
     assert traffic.prompt_levels(mix) == sorted(set(lv))
+
+
+class _Handle:
+    def __init__(self, req):
+        self.req, self.done, self.submit_s = req, False, time.perf_counter()
+
+    def result(self, timeout=None):
+        return types.SimpleNamespace(
+            status="done", ttft_s=0.001, e2e_s=0.002,
+            tokens=np.zeros(self.req.max_new, np.int32), reused_prefix_len=0,
+            kind="warm", fork_stats=None, token_times_s=None)
+
+
+class _Gateway:
+    """Finishes the oldest request in flight on each waiting pump, counts
+    how many were in flight at most, and, like the runtime's gateway,
+    keeps finished handles until the next pump.  At each submit no
+    finished handle may be held, here or by the loop: a handle holds its
+    engine's weights, which a function scaled to zero gives back before
+    the next request forks."""
+
+    def __init__(self):
+        self.live, self.peak, self.sent = [], 0, []
+        self.kept, self.finished = [], []
+
+    def submit(self, req):
+        assert not self.kept
+        assert all(ref() is None for ref in self.finished)
+        h = _Handle(req)
+        self.live.append(h)
+        self.sent.append(req)
+        self.peak = max(self.peak, len(self.live))
+        return h
+
+    def pump(self, until=None, timeout=None):
+        self.kept = []
+        if until is not None:
+            h = self.live.pop(0)
+            h.done = True
+            self.kept.append(h)
+            self.finished.append(weakref.ref(h))
+
+
+def _loop(callers, seed, n=16):
+    from benchlib import driver
+    mix = dict(_concurrent(), callers=callers)
+    seq = traffic.closed_loop(mix, 1, 1000, seed, n=n)
+    gw = _Gateway()
+    run = types.SimpleNamespace(rt=types.SimpleNamespace(gateway=gw),
+                                mix=mix, request=lambda r, arrival: r)
+    records, _ = driver.closed_loop(run, seq, 60.0, None)
+    return seq, gw, records
+
+
+@pytest.mark.parametrize("callers", [1, 3, 16])
+def test_concurrent_callers_keep_that_many_requests_in_flight(callers):
+    seq, gw, records = _loop(callers, 2**33 + 1)
+    assert gw.peak == callers
+    assert [id(r) for r in gw.sent] == [id(r) for r in seq]   # in order
+    assert [r["prompt_len"] for r in records] == [len(r.prompt) for r in seq]
+    assert all(r["status"] == "done" for r in records)
+
+
+def test_concurrent_callers_send_every_seeds_multiset_of_lengths():
+    mix = _concurrent()
+    got = []
+    for seed in (5, 2**33 + 7):
+        seq, _, records = _loop(mix["callers"], seed)
+        got.append((collections.Counter(r["prompt_len"] for r in records),
+                    collections.Counter(r["max_new"] for r in records)))
+    assert got[0] == got[1]
+    assert sorted(got[0][0]) == traffic.levels(mix["prompt_tokens"])
+    assert sorted(got[0][1]) == traffic.levels(mix["output_tokens"])
+    # 256 requests per caller unless told otherwise, the first the same
+    long = traffic.closed_loop(mix, 1, 1000, 5)
+    assert len(long) == 256 * mix["callers"]
+    assert [len(r.prompt) for r in long[:16]] == [
+        len(r.prompt) for r in traffic.closed_loop(mix, 1, 1000, 5, n=16)]
